@@ -45,7 +45,7 @@ import types
 from ..conditions import CapturedRun, ImmediateCondition, capture_run
 from ..errors import FutureCancelledError
 from .. import planning as plan_mod
-from ..rng import rng_scope
+from ..rng import call as rng_call, rng_scope
 from .base import (Backend, CompletionHandle, EventWaitMixin,
                    SlotCounterMixin, TaskSpec, register_backend)
 
@@ -159,7 +159,7 @@ class AsyncioBackend(SlotCounterMixin, EventWaitMixin, Backend):
                     future_label=task.label))
             else:
                 run = self._capture_seg(
-                    lambda: task.fn(*task.args, **task.kwargs), task, handle)
+                    lambda: rng_call(task.fn, task.args, task.kwargs), task, handle)
                 if run.error is None and inspect.isawaitable(run.value):
                     run = await self._drive(run, task, handle)
             if run.error is not None and \

@@ -12,7 +12,8 @@ template for multi-host runs, or hand-launched anywhere with network reach
 Spec kwargs (``plan("cluster", ...)`` / ``spec("cluster", ...)``):
 
 * ``workers=N`` — launch N local worker processes that connect back over
-  127.0.0.1 (default: ``available_cores()``).
+  127.0.0.1 (default: ``available_cores()``, and at most one per TPU chip
+  on a host with chips).
 * ``hosts=N`` or ``hosts=("nodeA", "nodeB")`` — where workers run. An int
   launches that many via :class:`~.launchers.LocalLauncher`; named hosts
   default to :class:`~.launchers.SSHLauncher` (the paper's
@@ -148,7 +149,7 @@ from .base import (Backend, CompletionHandle, EventWaitMixin, TaskSpec,
                    register_backend)
 from .blobstore import (DRIVER_STORE, PayloadRef, RemoteValue,
                         encode_backfill)
-from .launchers import WorkerProc, resolve_launcher
+from .launchers import LocalLauncher, WorkerProc, resolve_launcher
 from .transport import (FrameReader, TLSConfig, generate_self_signed_cert,
                         send_frame, serve_auth, server_tls_context)
 
@@ -292,6 +293,13 @@ class ClusterBackend(EventWaitMixin, Backend):
             self._n = len(self._hosts)
         #: who bootstraps workers; None = external (hand-launched) capacity
         self._launcher = resolve_launcher(launcher, hosts)
+        if hosts is None and not workers \
+                and isinstance(self._launcher, LocalLauncher):
+            # on a TPU host each launched worker owns one chip
+            n_chips = len(self._launcher.chips())
+            if n_chips:
+                self._n = min(self._n, n_chips)
+                self._hosts = ("127.0.0.1",) * self._n
         self._relaunch_backoff = max(float(relaunch_backoff), 0.0)
         self._relaunch_cap = max(float(relaunch_backoff_cap),
                                  self._relaunch_backoff)
@@ -2045,4 +2053,11 @@ class ClusterBackend(EventWaitMixin, Backend):
         """PIDs of the currently-connected workers (diagnostics/tests)."""
         with self._pool_cv:
             return [w.meta.get("pid") for w in self._all
+                    if w.ready and w.sock is not None]
+
+    def worker_devices(self) -> list:
+        """The chip each connected worker claimed at start-up, as its
+        hello reported it (``None`` for a worker without a chip)."""
+        with self._pool_cv:
+            return [w.meta.get("device") for w in self._all
                     if w.ready and w.sock is not None]

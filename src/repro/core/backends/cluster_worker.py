@@ -28,6 +28,9 @@ Flags for scheduler-launched fleets:
   from the driver (and bound reconnect attempts the same way), so a
   scheduler-launched worker cannot outlive a dead driver and squat its
   allocation forever. ``0`` (default): never.
+* ``REPRO_TPU_CHIP`` (set by :class:`~.launchers.LocalLauncher` on a TPU
+  host) — the worker claims that one chip before its hello and reports
+  it there (:func:`claim_chip`); a chip it cannot open kills it.
 * ``--token`` / ``--tls`` / ``--tls-ca`` — the driver's security settings
   (see the *security preamble* in ``transport.py``). Default from
   ``REPRO_CLUSTER_TOKEN`` / ``REPRO_CLUSTER_TLS`` /
@@ -88,8 +91,9 @@ import threading
 import time
 
 from ..errors import ChannelError
-from .transport import (TLSConfig, client_tls_context, dial_auth,
-                        recv_frame, send_frame, serve_auth,
+from .launchers import CHIP_ENV
+from .transport import (TLSConfig, become_executor, client_tls_context,
+                        dial_auth, recv_frame, send_frame, serve_auth,
                         server_tls_context)
 
 
@@ -210,9 +214,49 @@ def _peer_fetch(digest, addrs, timeout: float = 5.0, *, tls_ctx=None,
     return None
 
 
+def device_report() -> dict:
+    """The accelerator this process holds, as JAX reports it, plus the
+    chip the launcher assigned (``None`` for a worker without one) and the
+    device files the process has open — distinct workers on one host show
+    distinct files."""
+    import jax
+    devices = jax.devices()
+    files = set()
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            target = os.readlink(f"/proc/self/fd/{fd}")
+        except OSError:
+            continue
+        if target.startswith(("/dev/vfio/", "/dev/accel")) \
+                and target != "/dev/vfio/vfio":
+            files.add(target)
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices),
+            "chip": os.environ.get(CHIP_ENV), "pid": os.getpid(),
+            "device_files": sorted(files)}
+
+
+def claim_chip() -> "dict | None":
+    """A worker launched onto a TPU chip (``REPRO_TPU_CHIP`` set, with
+    ``JAX_PLATFORMS=tpu``) takes that chip before it says hello and
+    returns its :func:`device_report`. A chip it cannot open raises, so
+    the worker dies before hello and the driver reports it; it never
+    carries on on the CPU. ``None`` for a worker without a chip."""
+    if CHIP_ENV not in os.environ:
+        return None
+    report = device_report()
+    if report["platform"] != "tpu" or report["count"] != 1:
+        raise RuntimeError(
+            f"worker for TPU chip {os.environ[CHIP_ENV]} holds "
+            f"{report['count']} {report['platform']} device(s); a chip "
+            f"worker must hold exactly one TPU chip")
+    return report
+
+
 def _serve(sock: socket.socket, *, tag: str = "",
            max_idle_s: float = 0.0,
-           handshake_timeout: float = 30.0) -> str:
+           handshake_timeout: float = 30.0,
+           device: "dict | None" = None) -> str:
     """Serve one driver connection until it ends; returns why:
     ``"stop"`` (stop frame), ``"idle"`` (``max_idle_s`` with no driver
     frames), or ``"eof"`` (connection lost / driver died)."""
@@ -333,6 +377,8 @@ def _serve(sock: socket.socket, *, tag: str = "",
         meta["tag"] = tag
     if peer_srv.addr is not None:
         meta["peer"] = peer_srv.addr
+    if device is not None:
+        meta["device"] = device
     send_frame(sock, ("hello", meta), send_lock)
 
     # One reader thread owns every read on the driver socket: it serves
@@ -506,14 +552,16 @@ def _secure_dial(sock, host: str, *, token: str = "",
 def run_worker(host: str, port: int, *, connect_timeout: float = 30.0,
                tag: str = "", reconnect: bool = False,
                max_idle_s: float = 0.0, token: str = "",
-               tls: "TLSConfig | None" = None) -> None:
+               tls: "TLSConfig | None" = None,
+               device: "dict | None" = None) -> None:
     """Connect to the driver and resolve shipped futures until told to stop
     or the connection drops. Default: exit on disconnect and let the
     driver's relaunch policy self-heal; with ``reconnect=True`` keep
     redialing (scheduler-owned workers), bounded by ``max_idle_s``.
     ``token``/``tls`` must match the driver's security settings (launched
     workers inherit them via ``REPRO_CLUSTER_TOKEN`` / ``REPRO_CLUSTER_TLS``
-    / ``REPRO_CLUSTER_TLS_CA``)."""
+    / ``REPRO_CLUSTER_TLS_CA``). ``device`` is the chip this worker
+    claimed (:func:`claim_chip`), reported in its hello."""
     os.environ.setdefault("OMP_NUM_THREADS", "1")
     os.environ.setdefault("XLA_FLAGS", "--xla_cpu_multi_thread_eigen=false")
 
@@ -540,7 +588,8 @@ def run_worker(host: str, port: int, *, connect_timeout: float = 30.0,
             sock = _secure_dial(sock, host, token=token, tls=tls,
                                 timeout=connect_timeout)
             reason = _serve(sock, tag=tag, max_idle_s=max_idle_s,
-                            handshake_timeout=connect_timeout)
+                            handshake_timeout=connect_timeout,
+                            device=device)
         except (EOFError, ChannelError, OSError):
             # connection lost inside the init/security handshake (driver
             # mid-restart accepted then closed, credential mismatch): same
@@ -602,10 +651,13 @@ def main(argv=None) -> None:
         ap.error(f"address must be HOST:PORT, got {args.address!r}")
     tls = TLSConfig(cafile=args.tls_ca) if (args.tls or args.tls_ca) \
         else None
+    from ...cache import enable_compile_cache
+    enable_compile_cache()
+    become_executor()
     run_worker(host or "127.0.0.1", int(port),
                connect_timeout=args.connect_timeout, tag=args.tag,
                reconnect=args.reconnect, max_idle_s=args.max_idle_s,
-               token=args.token, tls=tls)
+               token=args.token, tls=tls, device=claim_chip())
 
 
 if __name__ == "__main__":

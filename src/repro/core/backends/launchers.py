@@ -8,7 +8,8 @@ This module is that half of the TCP cluster backend: a small
 
 * :class:`LocalLauncher` — subprocess-spawn workers on this machine. The
   default for ``workers=N`` and ``hosts=N``: ``spec("cluster", hosts=2)``
-  now runs end-to-end with zero hand-launched processes.
+  now runs end-to-end with zero hand-launched processes. On a host with
+  TPU chips each worker owns exactly one chip.
 * :class:`SSHLauncher`  — bootstrap over ``ssh`` (remote python path, env
   forwarding, optional reverse tunnel for NAT'd workers), mirroring
   ``makeClusterPSOCK``'s defaults. The default for named ``hosts=``.
@@ -40,6 +41,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import glob
 import os
 import re
 import shlex
@@ -63,6 +65,64 @@ EXTERNAL = "external"
 
 #: stderr lines retained per launched worker (surfaced on pre-hello death)
 _STDERR_KEEP_LINES = 50
+
+#: environment entry naming the TPU chip a launched worker owns; the
+#: worker claims that chip before its hello (see ``cluster_worker.py``)
+CHIP_ENV = "REPRO_TPU_CHIP"
+
+#: first per-chip libtpu port; chip ``i`` uses ``_CHIP_PORT_BASE + i``
+_CHIP_PORT_BASE = 8476
+
+
+def _tpu_device_files() -> int:
+    """TPU device files this host hands its processes: ``/dev/vfio/<n>``
+    (v5e and newer) or ``/dev/accel<n>`` (older chips). A machine given
+    part of a host sees every chip on the PCI bus but only its own files."""
+    vfio = [f for f in os.listdir("/dev/vfio") if f.isdigit()] \
+        if os.path.isdir("/dev/vfio") else []
+    return len(vfio) or len(glob.glob("/dev/accel[0-9]*"))
+
+
+def host_tpu_chips(env: "dict | None" = None) -> "list[str]":
+    """The TPU chips a worker launched with ``env`` (default: this
+    process's environment) may own, as libtpu numbers them: the inherited
+    ``TPU_VISIBLE_CHIPS`` list when there is one, else one per chip that
+    is both on the PCI bus and has a device file here. Found without
+    loading libtpu, so the launching process never initialises a JAX
+    backend. Empty when ``JAX_PLATFORMS`` leaves out ``tpu``, as CPU runs
+    and tests do."""
+    env = os.environ if env is None else env
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return []
+    visible = env.get("TPU_VISIBLE_CHIPS", "")
+    if visible:
+        return [c.strip() for c in visible.split(",") if c.strip()]
+    from jax._src import hardware_utils
+    n = min(hardware_utils.num_available_tpu_chips_and_device_id()[0],
+            _tpu_device_files())
+    return [str(i) for i in range(n)]
+
+
+def chip_env(chip: str) -> dict:
+    """Environment that gives one process exactly TPU chip ``chip`` of this
+    host, with the platform forced to ``tpu``: a worker that cannot open
+    its chip fails at start-up instead of falling back to the CPU."""
+    port = _CHIP_PORT_BASE + int(chip)
+    return {"JAX_PLATFORMS": "tpu", CHIP_ENV: chip,
+            "TPU_VISIBLE_CHIPS": chip,
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(port),
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+            "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{port}",
+            "TPU_MESH_CONTROLLER_PORT": str(port)}
+
+
+#: chip -> the local worker process that owns it, shared by every
+#: LocalLauncher in this process (a relaunch takes over a dead owner's chip)
+_CHIP_OWNERS: "dict[str, WorkerProc]" = {}
+_CHIP_LOCK = threading.Lock()
 
 
 def _src_root() -> str:
@@ -224,6 +284,10 @@ class LocalLauncher(Launcher):
       the driver quotes in death errors (default). Set ``False`` to let
       workers write straight to the driver's terminal instead (live
       library warnings over post-mortem diagnosis).
+
+    On a host with TPU chips (:meth:`chips`) each worker owns one chip: a
+    relaunch takes over a dead owner's chip, and a launch with every chip
+    owned by a live worker raises.
     """
 
     python: str = ""
@@ -233,6 +297,12 @@ class LocalLauncher(Launcher):
 
     local_only = True
 
+    def chips(self, extra_env=()) -> "list[str]":
+        """The TPU chips this launcher's workers own, one each
+        (:func:`host_tpu_chips` of the worker environment); empty off a
+        TPU host."""
+        return host_tpu_chips(self._worker_env(self.env + tuple(extra_env)))
+
     def launch(self, host, driver_addr, *, tag=None, extra_env=()):
         dhost, dport = driver_addr
         cmd = [self.python or sys.executable, "-m", WORKER_MODULE,
@@ -240,10 +310,28 @@ class LocalLauncher(Launcher):
         if tag:
             cmd += ["--tag", tag]
         cmd += list(self.worker_args)
-        return self._spawn(cmd, host or "127.0.0.1", tag,
-                           env=self._worker_env(self.env + tuple(extra_env)),
-                           capture_stderr=self.capture_stderr,
-                           tag_forwarded=bool(tag))
+        env = self._worker_env(self.env + tuple(extra_env))
+        chips = self.chips(extra_env)
+        if not chips:
+            return self._spawn(cmd, host or "127.0.0.1", tag, env=env,
+                               capture_stderr=self.capture_stderr,
+                               tag_forwarded=bool(tag))
+        with _CHIP_LOCK:
+            free = [c for c in chips
+                    if c not in _CHIP_OWNERS
+                    or _CHIP_OWNERS[c].poll() is not None]
+            if not free:
+                raise RuntimeError(
+                    f"no free TPU chip: all {len(chips)} chips of this "
+                    f"host ({','.join(chips)}) are owned by live workers, "
+                    f"and a chip worker never shares a chip or falls back "
+                    f"to the CPU")
+            env.update(chip_env(free[0]))
+            wp = self._spawn(cmd, host or "127.0.0.1", tag, env=env,
+                             capture_stderr=self.capture_stderr,
+                             tag_forwarded=bool(tag))
+            _CHIP_OWNERS[free[0]] = wp
+            return wp
 
     def describe(self) -> str:
         return f"local({self.python or sys.executable})"

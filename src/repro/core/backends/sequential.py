@@ -10,7 +10,7 @@ from __future__ import annotations
 
 from ..conditions import CapturedRun, capture_run
 from .. import planning as plan_mod
-from ..rng import rng_scope
+from ..rng import call as rng_call, rng_scope
 from .base import Backend, TaskSpec, register_backend
 
 
@@ -35,7 +35,7 @@ class SequentialBackend(Backend):
         with plan_mod.use_nested_stack():
             with rng_scope(task.seed_declared):
                 run = capture_run(
-                    lambda: task.fn(*task.args, **task.kwargs),
+                    lambda: rng_call(task.fn, task.args, task.kwargs),
                     capture_stdout=task.capture_stdout,
                     capture_conditions=task.capture_conditions,
                 )

@@ -26,7 +26,7 @@ from typing import Any
 from ..conditions import CapturedRun, ImmediateCondition, capture_run
 from ..errors import FutureCancelledError
 from .. import planning as plan_mod
-from ..rng import rng_scope
+from ..rng import call as rng_call, rng_scope
 from .base import (Backend, CompletionHandle, EventWaitMixin,
                    SlotCounterMixin, TaskSpec, register_backend)
 
@@ -124,7 +124,7 @@ class ThreadBackend(SlotCounterMixin, EventWaitMixin, Backend):
                 with plan_mod.use_nested_stack(self._nested):
                     with rng_scope(task.seed_declared):
                         run = capture_run(
-                            lambda: task.fn(*task.args, **task.kwargs),
+                            lambda: rng_call(task.fn, task.args, task.kwargs),
                             capture_stdout=task.capture_stdout,
                             capture_conditions=task.capture_conditions,
                             immediate_emit=handle.immediate.put,

@@ -213,11 +213,14 @@ after TLS + token preamble on the same framed transport):
 
 from __future__ import annotations
 
+import copyreg
 import dataclasses
 import hmac
+import io
 import os
 import pickle
 import struct
+import sys
 import threading
 import zlib
 from collections import OrderedDict
@@ -509,14 +512,53 @@ def dial_auth(sock, token: str, *, timeout: float = AUTH_TIMEOUT_S) -> None:
 # Frame encoding
 # --------------------------------------------------------------------------
 
+def _rebuild_array(host, weak_type: bool):
+    """Unpickle side of a ``jax.Array`` inside a frame: a device array or
+    the host ``ndarray``, by :func:`_decode_jax_to_device`."""
+    if not _decode_jax_to_device():
+        return host
+    import jax
+    out = jax.device_put(host)
+    out.aval = out.aval.update(weak_type=weak_type)
+    return out
+
+
+def _reduce_jax_array(x):
+    import numpy as np
+    return _rebuild_array, (np.asarray(x), x.aval.weak_type)
+
+
+_FRAME_DISPATCH: "dict | None" = None
+
+
+def _frame_dispatch_table() -> "dict | None":
+    """Pickle dispatch table for frames: once jax is imported, jax arrays
+    travel as host ndarrays (out-of-band) and are rebuilt by
+    :func:`_rebuild_array` instead of jax's own reducer, which would put
+    them on a device in whichever process unpickles the frame."""
+    global _FRAME_DISPATCH
+    if _FRAME_DISPATCH is None and "jax" in sys.modules:
+        from jax._src.array import ArrayImpl
+        table = copyreg.dispatch_table.copy()
+        table[ArrayImpl] = _reduce_jax_array
+        _FRAME_DISPATCH = table
+    return _FRAME_DISPATCH
+
+
 def encode_frame_parts(obj: Any) -> list:
     """Encode ``obj`` as a list of buffers (first one owns the length
     prefix). Large ``PickleBuffer``/ndarray payloads stay out-of-band:
     they are returned as memoryviews of the caller's memory, never copied
     into a contiguous frame."""
     pbufs: list = []
-    blob = pickle.dumps(obj, protocol=pickle.HIGHEST_PROTOCOL,
-                        buffer_callback=pbufs.append)
+    out = io.BytesIO()
+    pickler = pickle.Pickler(out, protocol=pickle.HIGHEST_PROTOCOL,
+                             buffer_callback=pbufs.append)
+    table = _frame_dispatch_table()
+    if table is not None:
+        pickler.dispatch_table = table
+    pickler.dump(obj)
+    blob = out.getvalue()
     if not pbufs:
         flag = _RAW
         if len(blob) >= COMPRESS_THRESHOLD:
@@ -815,10 +857,8 @@ def _quantize_blob(arr, kind: str, ef) -> bytes:
     if ef is not None:
         (q, scale), _deq = ef.compress(arr)
     else:
-        from ...optim.compression import quantize_int8
-        import jax.numpy as jnp
-        q, scale = quantize_int8(jnp.asarray(arr, jnp.float32))
-    q = np.asarray(q, np.int8)
+        from ...optim.compression import quantize_int8_host
+        q, scale = quantize_int8_host(arr)
     meta = {"dtype": arr.dtype.name, "shape": arr.shape, "kind": kind,
             "scale": float(scale)}
     return _pack_meta(P_INT8, meta, np.ascontiguousarray(q))
@@ -917,13 +957,40 @@ def encode_payload(value: Any, *, name: "str | None" = None,
     return bytes((P_PICKLE,)) + pickled
 
 
+#: set by :func:`become_executor` in worker processes
+_EXECUTOR = False
+
+
+def become_executor() -> None:
+    """Mark this process as one that runs shipped futures (a worker's
+    entry point calls this once). Owning its device is a worker's job, so
+    it always decodes ``jax.Array`` values as device arrays."""
+    global _EXECUTOR
+    _EXECUTOR = True
+
+
+def _decode_jax_to_device() -> bool:
+    """Whether a ``jax.Array`` sent to this process comes back as one: in
+    a worker always (:func:`become_executor`); in a client (the driver)
+    only once it has initialised a JAX backend itself, so a driver that
+    dispatches chip work never takes a device just to decode a result and
+    gets the host ``ndarray`` instead. The one rule for payloads
+    (:func:`decode_payload`) and frames (:func:`_rebuild_array`)."""
+    if _EXECUTOR:
+        return True
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
+
+
 def decode_payload(blob) -> "tuple[Any, bool]":
     """Decode a payload blob; returns ``(value, cacheable)``.
 
     ``cacheable`` marks values safe to share across tasks from the worker's
     decoded-object cache: arrays (handed out **read-only** — a task that
     wants to scribble on a shipped global must copy it first) and
-    bytes/str. Mutable pickles are decoded fresh per task.
+    bytes/str. Mutable pickles are decoded fresh per task. Whether a
+    ``jax.Array`` comes back as one or as the host ``ndarray`` is
+    :func:`_decode_jax_to_device`'s rule.
     """
     import numpy as np
     view = memoryview(blob)
@@ -941,7 +1008,7 @@ def decode_payload(blob) -> "tuple[Any, bool]":
         dtype = _np_dtype(meta["dtype"])
         if x.dtype != dtype:
             x = x.astype(dtype)
-        if meta["kind"] == "jax":
+        if meta["kind"] == "jax" and _decode_jax_to_device():
             import jax.numpy as jnp
             return jnp.asarray(x), True
         x.flags.writeable = False
@@ -950,7 +1017,7 @@ def decode_payload(blob) -> "tuple[Any, bool]":
         meta, body = _unpack_meta(blob)
         arr = np.frombuffer(body, _np_dtype(meta["dtype"])) \
             .reshape(meta["shape"])
-        if meta["kind"] == "jax":
+        if meta["kind"] == "jax" and _decode_jax_to_device():
             import jax.numpy as jnp
             return jnp.asarray(arr), True
         return arr, True                     # frombuffer views are read-only

@@ -63,7 +63,7 @@ def execute_shipped(blob: bytes, emit, resolve_ref=None) -> Any:
 
     from ..conditions import capture_run
     from ..globals_capture import payload_resolver, unship_function
-    from ..rng import rng_scope
+    from ..rng import call as rng_call, rng_scope
 
     with payload_resolver(resolve_ref) if resolve_ref is not None \
             else contextlib.nullcontext():
@@ -74,7 +74,7 @@ def execute_shipped(blob: bytes, emit, resolve_ref=None) -> Any:
         fn = unship_function(payload["fn"], resolve_ref=resolve_ref)
     with rng_scope(payload["seed_declared"]):
         run = capture_run(
-            lambda: fn(*payload["args"], **payload["kwargs"]),
+            lambda: rng_call(fn, payload["args"], payload["kwargs"]),
             capture_stdout=payload["capture_stdout"],
             capture_conditions=payload["capture_conditions"],
             immediate_emit=emit,
@@ -187,7 +187,9 @@ def worker_main(conn, nested_stack_blob: bytes, session_seed: int,
     from .. import rng as rng_mod
     from ..errors import ChannelError
     from ..state import PipeStateClient, state_context
+    from .transport import become_executor
 
+    become_executor()
     nested = pickle.loads(nested_stack_blob)
     plan_mod._TLS.stack = tuple(nested)         # worker-local plan stack
     rng_mod.set_session_seed(session_seed)

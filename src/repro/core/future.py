@@ -355,8 +355,8 @@ class Future:
         frozen, snapshot, packages = _freeze(fn, globals)
         self._snapshot, self._packages = snapshot, packages
         if self.seed_declared and _accepts_kwarg(fn, "key"):
-            key = rng_mod.stream_key(self._stream_index)
-            kwargs = dict(kwargs, key=key)
+            kwargs = dict(kwargs, key=rng_mod.StreamKey(
+                rng_mod._session_seed, self._stream_index))
         self._fn, self._args, self._kwargs = frozen, args, kwargs
 
         if not lazy:
@@ -1275,7 +1275,7 @@ def merge(futures: Sequence[Future], *, label: str | None = None) -> Future:
     def _chunk(subs=subs):
         out = []
         for fn, args, kwargs, _seed in subs:
-            out.append(fn(*args, **kwargs))
+            out.append(rng_mod.call(fn, args, kwargs))
         return out
 
     merged = Future(_chunk, (), {}, label=label or

@@ -6,7 +6,9 @@ JAX's counter-based threefry PRNG gives us the same guarantee with a simpler
 construction: every future receives ``fold_in(session_key, future_counter)``
 and every map-reduce **element** receives ``fold_in(session_key,
 element_index)`` — indexed by element, never by worker or chunk, so results
-are invariant to chunking and scheduling.
+are invariant to chunking and scheduling. The key is derived where the body
+runs, from ``(session seed, index)``, never in the process that creates the
+future.
 
 Like the paper, an RNG draw inside a future that did *not* declare ``seed=``
 triggers an informative :class:`RNGMisuseWarning` (detection is cheap: we
@@ -17,7 +19,7 @@ from __future__ import annotations
 
 import threading
 import warnings
-from typing import Iterator
+from typing import NamedTuple
 
 import jax
 import numpy as np
@@ -45,16 +47,30 @@ def next_stream_index() -> int:
         return idx
 
 
-def stream_key(index: int) -> jax.Array:
+def stream_key(index: int, seed: "int | None" = None) -> jax.Array:
     """Deterministic per-stream key: fold_in(session, index)."""
-    return jax.random.fold_in(jax.random.PRNGKey(_session_seed), index)
+    base = _session_seed if seed is None else seed
+    return jax.random.fold_in(jax.random.PRNGKey(base), index)
 
 
-def element_keys(n: int, *, base_index: int = 0) -> Iterator[jax.Array]:
-    """Per-element keys for map-reduce — invariant to chunking/backends."""
-    base = jax.random.PRNGKey(_session_seed)
-    for i in range(n):
-        yield jax.random.fold_in(base, base_index + i)
+class StreamKey(NamedTuple):
+    """A seeded future's RNG stream as ``(session seed, stream index)``.
+
+    The creating process records only these two integers; the process that
+    runs the body derives the key (:func:`call`). So a driver that only
+    dispatches futures never initialises a JAX backend, and the key bits
+    are the same on every backend."""
+    seed: int
+    index: int
+
+
+def call(fn, args: tuple, kwargs: dict):
+    """``fn(*args, **kwargs)`` with a :class:`StreamKey` ``key`` argument
+    turned into its key here, in the process that runs the body."""
+    key = kwargs.get("key")
+    if isinstance(key, StreamKey):
+        kwargs = dict(kwargs, key=stream_key(key.index, key.seed))
+    return fn(*args, **kwargs)
 
 
 # --------------------------------------------------------------------------

@@ -7,4 +7,4 @@ mlstm_scan        chunkwise-parallel mLSTM matrix memory (xlstm)
 slstm_scan        sequential sLSTM with VMEM-resident state (xlstm)
 """
 
-from . import ops, ref  # noqa: F401
+from . import ref  # noqa: F401

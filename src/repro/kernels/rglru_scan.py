@@ -4,7 +4,7 @@ The recurrence h_t = a_t * h_{t-1} + b_t is elementwise over the width dim
 (pure VPU work, HBM-bandwidth bound). TPU adaptation: tile (width) across
 parallel grid cells and (time) across the sequential innermost grid axis;
 the carried state h lives in VMEM scratch. Within a time chunk the scan
-runs as an unrolled-by-8 fori_loop over rows already resident in VMEM, so
+runs as a fori_loop over rows already resident in VMEM, so
 HBM traffic is exactly one read of (x, a, b) and one write of y.
 
 Layout: all operands (B, S, W) fp32. Grid: (B, NW, NS), NS sequential.
@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
-
 
 def _rglru_kernel(a_ref, xs_ref, h0_ref, y_ref, h_scr, *,
                   cs: int):
@@ -30,12 +28,12 @@ def _rglru_kernel(a_ref, xs_ref, h0_ref, y_ref, h_scr, *,
     def _init():
         h_scr[...] = h0_ref[0]                       # (1, bw) initial state
 
-    a = a_ref[0]                                     # (cs, bw) decay
-    x = xs_ref[0]                                    # (cs, bw) scaled input
-
     def step(t, h):
-        h = a[t][None, :] * h + x[t][None, :]
-        y_ref[0, t, :] = h[0]
+        # one (1, bw) row of decay and scaled input, read straight from the
+        # VMEM block: Mosaic has no dynamic_slice on loaded values
+        row = pl.ds(t, 1)
+        h = a_ref[0, row, :] * h + xs_ref[0, row, :]
+        y_ref[0, row, :] = h
         return h
 
     h = jax.lax.fori_loop(0, cs, step, h_scr[...])
@@ -78,7 +76,7 @@ def rglru_scan(x: jax.Array, a_gate: jax.Array, i_gate: jax.Array,
         out_specs=pl.BlockSpec((1, cs, bw), lambda ib, iw, isq: (ib, isq, iw)),
         out_shape=jax.ShapeDtypeStruct((b, s, w), x.dtype),
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, xs, h0[:, None, :])

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat
-
 
 def _slstm_kernel(z_ref, i_ref, f_ref, o_ref, rz_ref, ri_ref, rf_ref,
                   ro_ref, h_out_ref, c_scr, n_scr, h_scr, m_scr, *,
@@ -96,7 +94,7 @@ def slstm_scan(z, i, f, o, rz, ri, rf, ro, *, cs: int = 512,
         out_specs=seq_spec,
         out_shape=jax.ShapeDtypeStruct((b, nh, s, hd), z.dtype),
         scratch_shapes=[pltpu.VMEM((1, hd), jnp.float32)] * 4,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(z, i, f, o, rz, ri, rf, ro)

@@ -13,15 +13,18 @@ The driver:
     ``add_done_callback`` (from the cluster driver's select loop) instead
     of the driver polling ``resolved()`` in a sleep loop;
   * re-dispatches on FutureError (node failure -> restart; the pod pool
-    self-heals underneath);
+    self-heals underneath), at most ``MAX_REDISPATCH`` times per pod per
+    round, so a pod that dies every time raises instead of looping;
   * optionally races a speculative duplicate of the slowest pod
     (``future_either`` pattern = straggler mitigation);
   * compresses the delta exchange (int8 + error feedback per pod);
   * applies a Nesterov outer step and async-checkpoints via a future.
 
-On real TPU pods the same loop runs with the cluster backend's transport
-swapped for the pod controller RPC; in-pod SPMD comes from jit + the
-production mesh (launch/dryrun.py proves those programs compile).
+The driver never touches a device: the initial parameters come from a
+future on pod 0 and their structure from ``jax.eval_shape``, and the
+delta averaging runs in numpy. On a TPU host each pod worker owns one chip
+(see ``core/backends/launchers.py``), so ``pods`` is at most the host's
+chip count there.
 
 Run: PYTHONPATH=src python -m repro.launch.train --arch xlstm-125m --pods 2
 """
@@ -36,7 +39,11 @@ from typing import Any
 import numpy as np
 
 from ..core import FutureError, Waiter, future, plan, value
-from ..optim.compression import ErrorFeedback, dequantize_tree, quantize_tree
+from ..optim.compression import ErrorFeedback
+
+#: re-dispatches of one pod within one round after a FutureError; a pod
+#: that dies on every try (out of device memory, a runtime abort) raises
+MAX_REDISPATCH = 3
 
 
 @dataclasses.dataclass
@@ -61,6 +68,46 @@ class PodRunConfig:
     straggle_s: float = 0.0
 
 
+def _pod_state(arch: str, smoke: bool, lr: float, local_steps: int,
+               seed: int) -> dict:
+    """This pod worker's model, initial parameters and jitted step, built
+    once and kept between rounds (pods are long-lived processes; re-jitting
+    per round would dominate)."""
+    import jax
+    from repro.configs import get_arch
+    from repro.models import Model
+    from repro.optim import AdamWConfig
+    from repro.train.step import make_train_step
+
+    import repro.launch.train as _self
+    cache = getattr(_self, "_POD_CACHE", None)
+    ckey = (arch, smoke, lr, local_steps, seed)
+    if cache is None or cache.get("key") != ckey:
+        cfg = get_arch(arch, smoke=smoke)
+        model = Model(cfg)
+        template = jax.jit(model.init)(jax.random.PRNGKey(seed))
+        # the state is rebuilt from the shipped parameters every round, so
+        # the step may reuse its buffers
+        step = jax.jit(make_train_step(
+            model, AdamWConfig(lr=lr, warmup_steps=0,
+                               total_steps=max(local_steps, 1))),
+            donate_argnums=0)
+        cache = {"key": ckey, "cfg": cfg, "template": template,
+                 "step": step}
+        _self._POD_CACHE = cache
+    return cache
+
+
+def init_params(arch: str, smoke: bool, lr: float, local_steps: int,
+                seed: int) -> "list[np.ndarray]":
+    """Executed inside a pod worker: the model's initial parameters as
+    host float32 leaves, so the driver needs no device to get them."""
+    import jax
+    template = _pod_state(arch, smoke, lr, local_steps, seed)["template"]
+    return [np.asarray(x, np.float32)
+            for x in jax.tree_util.tree_leaves(template)]
+
+
 def pod_round(arch: str, smoke: bool, params_flat: "list[np.ndarray]",
               round_idx: int, pod_id: int, n_pods: int,
               local_steps: int, batch: int, seq: int, lr: float,
@@ -80,56 +127,38 @@ def pod_round(arch: str, smoke: bool, params_flat: "list[np.ndarray]",
         time.sleep(straggle_s)
     import jax
     import jax.numpy as jnp
-    from repro.configs import get_arch
     from repro.core import signal_progress
     from repro.data import synth_batch
-    from repro.models import Model
-    from repro.optim import AdamWConfig, adamw
-    from repro.train.step import make_train_step
+    from repro.optim import adamw
     from repro.train.state import TrainState
 
-    # persistent-worker cache: model/template/jitted step survive between
-    # rounds (pods are long-lived processes; re-jitting per round would
-    # dominate the simulation)
-    import repro.launch.train as _self
-    cache = getattr(_self, "_POD_CACHE", None)
-    ckey = (arch, smoke, lr, local_steps)
-    if cache is None or cache.get("key") != ckey:
-        cfg = get_arch(arch, smoke=smoke)
-        model = Model(cfg)
-        template = model.init(jax.random.PRNGKey(seed))
-        step = jax.jit(make_train_step(
-            model, AdamWConfig(lr=lr, warmup_steps=0,
-                               total_steps=max(local_steps, 1))))
-        cache = {"key": ckey, "cfg": cfg, "model": model,
-                 "template": template, "step": step}
-        _self._POD_CACHE = cache
-    cfg, model = cache["cfg"], cache["model"]
-    template, step_fn = cache["template"], cache["step"]
-
-    leaves, treedef = jax.tree_util.tree_flatten(template)
+    pod = _pod_state(arch, smoke, lr, local_steps, seed)
+    cfg, step_fn = pod["cfg"], pod["step"]
+    leaves, treedef = jax.tree_util.tree_flatten(pod["template"])
     params = jax.tree_util.tree_unflatten(
         treedef, [jnp.asarray(a, dtype=l.dtype)
                   for a, l in zip(params_flat, leaves)])
     state = TrainState(params, adamw.init_state(params))
 
     loss = float("nan")
+    step_s = []                        # host clock; the first one compiles
     for i in range(local_steps):
         data = synth_batch(cfg, batch=batch, seq=seq, seed=seed,
                            step=round_idx * local_steps + i, shard=pod_id,
                            n_shards=n_pods)
+        t0 = time.perf_counter()
         data = {k: jnp.asarray(v) for k, v in data.items()}
         state, metrics = step_fn(state, data)
-        loss = float(metrics["loss"])
+        loss = float(metrics["loss"])          # waits for the device
+        step_s.append(time.perf_counter() - t0)
     signal_progress(f"pod {pod_id} round {round_idx} loss={loss:.4f}")
 
-    new_leaves = jax.tree_util.tree_leaves(state.params)
     delta = [np.asarray(n, np.float32) - np.asarray(o, np.float32)
-             for n, o in zip(new_leaves, leaves
-                             if round_idx < 0 else
-                             [jnp.asarray(a) for a in params_flat])]
+             for n, o in zip(jax.tree_util.tree_leaves(state.params),
+                             params_flat)]
     return {"pod": pod_id, "round": round_idx, "loss": loss,
-            "delta": delta, "tokens": local_steps * batch * seq}
+            "delta": delta, "tokens": local_steps * batch * seq,
+            "step_s": step_s}
 
 
 class MultiPodDriver:
@@ -140,10 +169,12 @@ class MultiPodDriver:
         from repro.configs import get_arch
         from repro.models import Model
         self._model_cfg = get_arch(cfg.arch, smoke=cfg.smoke)
-        template = Model(self._model_cfg).init(jax.random.PRNGKey(cfg.seed))
-        self.treedef = jax.tree_util.tree_structure(template)
-        self.params = [np.asarray(x, np.float32)
-                       for x in jax.tree_util.tree_leaves(template)]
+        shapes = jax.eval_shape(lambda: Model(self._model_cfg).init(
+            jax.random.PRNGKey(cfg.seed)))
+        self.treedef = jax.tree_util.tree_structure(shapes)
+        self.params = value(future(
+            init_params, cfg.arch, cfg.smoke, cfg.lr, cfg.local_steps,
+            cfg.seed, label="init-params"))
         self.velocity = [np.zeros_like(p) for p in self.params]
         self.ef = [ErrorFeedback() for _ in range(cfg.pods)]
         self.history: list[dict] = []
@@ -177,6 +208,7 @@ class MultiPodDriver:
         owner = {id(f): pod for pod, cands in fs.items() for f in cands}
         waiter = Waiter(f for cands in fs.values() for f in cands)
         results: dict[int, dict] = {}
+        redispatched = dict.fromkeys(fs, 0)
         t0 = time.time()
         speculated = False
         while len(results) < c.pods:
@@ -203,8 +235,13 @@ class MultiPodDriver:
                     continue
                 try:
                     results[pod] = value(f)
-                except FutureError:
+                except FutureError as exc:
                     # node failure: pool self-healed; re-dispatch
+                    redispatched[pod] += 1
+                    if redispatched[pod] > MAX_REDISPATCH:
+                        raise RuntimeError(
+                            f"pod {pod} failed {redispatched[pod]} times in "
+                            f"round {rnd}; last failure: {exc}") from exc
                     cands = fs[pod]
                     cands.remove(f)
                     nf = self._dispatch(pod, rnd)
@@ -234,7 +271,8 @@ class MultiPodDriver:
         loss = float(np.mean([results[p]["loss"] for p in range(c.pods)]))
         rec = {"round": rnd, "loss": loss,
                "tokens": sum(results[p]["tokens"] for p in range(c.pods)),
-               "wall_s": time.time() - t0}
+               "wall_s": time.time() - t0,
+               "step_s": [results[p]["step_s"] for p in range(c.pods)]}
         self.history.append(rec)
         return rec
 
@@ -273,6 +311,8 @@ def main() -> None:
     ap.add_argument("--no-compress", action="store_true")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    from ..cache import enable_compile_cache
+    enable_compile_cache()
     cfg = PodRunConfig(arch=args.arch, pods=args.pods, rounds=args.rounds,
                        local_steps=args.local_steps, batch=args.batch,
                        seq=args.seq, compress=not args.no_compress,

@@ -2,9 +2,8 @@
 
 Everything here is written to lower cleanly under jit + GSPMD sharding:
 einsum-based attention, no data-dependent python control flow, explicit
-dtypes. The hot paths have Pallas twins in repro.kernels selected via
-``kernel_impl="pallas"`` (validated in interpret mode on CPU; on-TPU builds
-use them for real).
+dtypes. Pallas twins of some hot paths live in ``repro.kernels``; the
+models do not call them.
 """
 
 from __future__ import annotations

@@ -207,9 +207,8 @@ def moe_apply_manual(p: Params, x: jax.Array, dims: MoEDims, mesh,
         y = jax.lax.psum(y, "model")           # f32 psum (see note above)
         return y, aux
 
-    from ..compat import shard_map
     manual = {dp_axis, "model"}
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_axis, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),

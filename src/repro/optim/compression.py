@@ -6,7 +6,7 @@ cross-pod gradient all-reduce is the collective to compress:
 * :func:`quantize_int8` / :func:`dequantize_int8` — per-tensor symmetric
   int8 with fp32 scale (4x over fp32, 2x over bf16).
 * :class:`ErrorFeedback` — residual accumulation so compression error is
-  re-injected next step (EF-SGD; keeps convergence).
+  re-injected next step (EF-SGD; keeps convergence), on the host.
 * :func:`topk_sparsify` — magnitude top-k with index+value encoding.
 
 Used by the multi-pod launcher (launch/train.py) and benchmarked in
@@ -19,6 +19,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def quantize_int8(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -31,15 +32,13 @@ def dequantize_int8(q: jax.Array, scale: jax.Array) -> jax.Array:
     return q.astype(jnp.float32) * scale
 
 
-def quantize_tree(grads: Any) -> Any:
-    return jax.tree_util.tree_map(lambda g: quantize_int8(
-        g.astype(jnp.float32)), grads)
-
-
-def dequantize_tree(qtree: Any) -> Any:
-    return jax.tree_util.tree_map(
-        lambda pair: dequantize_int8(*pair), qtree,
-        is_leaf=lambda t: isinstance(t, tuple) and len(t) == 2)
+def quantize_int8_host(x) -> tuple[np.ndarray, np.float32]:
+    """:func:`quantize_int8` in numpy, for processes that hold no device
+    (a driver that only dispatches pod work)."""
+    x = np.asarray(x, np.float32)
+    scale = np.maximum(np.max(np.abs(x)), np.float32(1e-12)) / np.float32(127)
+    q = np.clip(np.round(x / scale), -127, 127).astype(np.int8)
+    return q, np.float32(scale)
 
 
 def topk_sparsify(x: jax.Array, frac: float = 0.01,
@@ -60,22 +59,21 @@ def topk_restore(vals: jax.Array, idx: jax.Array, shape: tuple) -> jax.Array:
 class ErrorFeedback:
     """Residual error feedback: compress(g + e); e' = (g + e) - decompressed.
 
-    State lives host-side per pod (one pytree), applied around the cross-pod
-    reduce in the launcher.
+    State lives host-side per pod (one pytree of numpy arrays), applied
+    around the cross-pod reduce in the launcher; no device is touched.
     """
 
     def __init__(self):
         self.residual: Any | None = None
 
     def compress(self, grads: Any) -> tuple[Any, Any]:
+        grads = jax.tree_util.tree_map(
+            lambda g: np.asarray(g, np.float32), grads)
         if self.residual is not None:
-            grads = jax.tree_util.tree_map(
-                lambda g, r: g.astype(jnp.float32) + r, grads, self.residual)
-        else:
-            grads = jax.tree_util.tree_map(
-                lambda g: g.astype(jnp.float32), grads)
-        q = quantize_tree(grads)
-        deq = dequantize_tree(q)
-        self.residual = jax.tree_util.tree_map(
-            lambda g, d: g - d, grads, deq)
+            grads = jax.tree_util.tree_map(np.add, grads, self.residual)
+        q = jax.tree_util.tree_map(quantize_int8_host, grads)
+        deq = jax.tree_util.tree_map(
+            lambda pair: pair[0].astype(np.float32) * pair[1], q,
+            is_leaf=lambda t: isinstance(t, tuple) and len(t) == 2)
+        self.residual = jax.tree_util.tree_map(np.subtract, grads, deq)
         return q, deq

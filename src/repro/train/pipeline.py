@@ -166,8 +166,7 @@ def make_pipeline_loss(model: Model, mesh, *, microbatches: int,
         bspecs = jax.tree_util.tree_map(
             lambda x: P(*([None] * x.ndim)), batch)
         # manual over 'pod' only; data/model stay auto (GSPMD in-pod)
-        from ..compat import shard_map
-        fn = shard_map(pipelined, mesh=mesh,
+        fn = jax.shard_map(pipelined, mesh=mesh,
                        in_specs=(pspecs, bspecs), out_specs=P(),
                        axis_names={"pod"}, check_vma=False)
         return fn(params, batch)

@@ -123,6 +123,25 @@ def test_map_matches_sequential(backend):
     assert future_map(lambda v: v * v, xs) == [v * v for v in xs]
 
 
+@pytest.mark.parametrize("n", [4, 8192], ids=["inline", "payload-ref"])
+def test_jax_array_global_stays_a_jax_array(backend, n):
+    """A ``jax.Array`` global reaches the body as a ``jax.Array`` on every
+    backend, in a fresh worker too: pickled with the task, or
+    content-addressed as a payload ref (8192 float32 is above ~16 KiB)."""
+    import jax.numpy as jnp
+    x = jnp.arange(n, dtype=jnp.float32)
+
+    def body():
+        import jax
+        y = x.at[0].set(5.0).block_until_ready()
+        return (type(x).__name__, isinstance(x, jax.Array), float(y[0]),
+                float(y[1:].sum()))
+
+    kind, is_jax, first, rest = value(future(body))
+    assert is_jax, kind
+    assert first == 5.0 and rest == float(sum(range(1, n)))
+
+
 def test_nested_parallelism_protection(backend):
     """A future created inside a future must default to the sequential
     (popped) stack — no N^2 explosion (paper §Nested parallelism)."""

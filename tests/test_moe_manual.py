@@ -9,7 +9,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ.setdefault("JAX_PLATFORMS", "cpu")   # no TPU probing in the sandbox
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh
 from repro.models.moe import MoEDims, moe_init, moe_apply, moe_apply_manual
 
 # capacity high enough that neither path drops tokens -> exact equality
@@ -22,7 +21,7 @@ x = jax.random.normal(jax.random.fold_in(key, 1), (4, 16, 64))
 y_auto, aux_auto = moe_apply(p, x, dims)
 
 mesh = jax.make_mesh((2, 4), ("data", "model"))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_man, aux_man = jax.jit(
         lambda p, x: moe_apply_manual(p, x, dims, mesh))(p, x)
 
@@ -37,7 +36,7 @@ print("manual == auto OK")
 def loss(p):
     y, aux = moe_apply_manual(p, x, dims, mesh)
     return jnp.sum(y ** 2) + aux
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     g = jax.jit(jax.grad(loss))(p)
 gn = sum(float(jnp.sum(jnp.abs(l))) for l in jax.tree_util.tree_leaves(g))
 assert np.isfinite(gn) and gn > 0
@@ -48,7 +47,7 @@ dims_pad = MoEDims(d_model=64, n_experts=10, top_k=2, d_expert=32,
                    capacity_factor=16.0, n_experts_padded=12)
 p2 = moe_init(jax.random.fold_in(key, 2), dims_pad, jnp.float32)
 y2_auto, _ = moe_apply(p2, x, dims_pad)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     y2_man, _ = jax.jit(
         lambda p, x: moe_apply_manual(p, x, dims_pad, mesh))(p2, x)
 np.testing.assert_allclose(np.asarray(y2_man), np.asarray(y2_auto),

@@ -76,3 +76,23 @@ def test_multipod_checkpoints(tmp_path):
     driver = MultiPodDriver(_cfg(rounds=2, ckpt_dir=str(tmp_path / "ck")))
     driver.run()
     assert driver.ckpt.latest_step() == 2
+
+
+def test_multipod_pod_that_always_dies_raises(monkeypatch):
+    """A pod that dies on every try (device OOM, runtime abort) is
+    re-dispatched at most MAX_REDISPATCH times, then the round raises."""
+    import repro.launch.train as train
+    from repro.core import future
+    monkeypatch.setattr(train, "MAX_REDISPATCH", 1)
+
+    def always_dies():
+        import os
+        os._exit(43)
+
+    class Doomed(MultiPodDriver):
+        def _dispatch(self, pod, rnd, *, speculative=False):
+            return future(always_dies, label=f"pod{pod}-round{rnd}")
+
+    driver = Doomed(_cfg(pods=1, rounds=1))
+    with pytest.raises(RuntimeError, match="pod 0 failed 2 times"):
+        driver.run_round(0)

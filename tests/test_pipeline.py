@@ -11,7 +11,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ.setdefault("JAX_PLATFORMS", "cpu")   # no TPU probing in the sandbox
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import set_mesh
 from repro.configs import get_arch
 from repro.models import Model
 from repro.data import synth_batch
@@ -28,7 +27,7 @@ plain_loss, _ = model.loss(params, batch)
 mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
 pp_params = split_stage_params(params, 2)
 loss_fn = make_pipeline_loss(model, mesh, microbatches=2, remat="none")
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     pp_loss = jax.jit(loss_fn)(pp_params, batch)
 print("plain", float(plain_loss), "pipeline", float(pp_loss))
 np.testing.assert_allclose(float(pp_loss), float(plain_loss),
