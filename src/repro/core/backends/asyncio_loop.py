@@ -169,7 +169,7 @@ class AsyncioBackend(SlotCounterMixin, EventWaitMixin, Backend):
                         f"future {task.label!r} cancelled",
                         future_label=task.label),
                     stdout=run.stdout, conditions=run.conditions,
-                    immediate=run.immediate, wall_time_s=run.wall_time_s)
+                    immediate=run.immediate)
             handle.run = run
         except asyncio.CancelledError:
             handle.run = CapturedRun(error=FutureCancelledError(
@@ -191,7 +191,6 @@ class AsyncioBackend(SlotCounterMixin, EventWaitMixin, Backend):
         run = CapturedRun(stdout=head.stdout,
                           conditions=head.conditions,
                           immediate=head.immediate,
-                          wall_time_s=head.wall_time_s,
                           rng_touched=head.rng_touched)
         if not hasattr(it, "send"):
             # a non-generator awaitable runs no user code per segment (e.g.
@@ -215,7 +214,6 @@ class AsyncioBackend(SlotCounterMixin, EventWaitMixin, Backend):
             run.stdout += seg.stdout
             run.conditions += seg.conditions
             run.immediate += seg.immediate
-            run.wall_time_s += seg.wall_time_s
             run.rng_touched |= seg.rng_touched
             if seg.error is not None:
                 if isinstance(seg.error, StopIteration):

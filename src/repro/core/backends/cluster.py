@@ -145,6 +145,7 @@ from ..conditions import CapturedRun, ImmediateCondition
 from ..errors import (ChannelError, FutureCancelledError, FutureError,
                       LineageExhaustedError, WorkerDiedError)
 from .. import planning as plan_mod
+from .. import tracing
 from .base import (Backend, CompletionHandle, EventWaitMixin, TaskSpec,
                    register_backend)
 from .blobstore import (DRIVER_STORE, PayloadRef, RemoteValue,
@@ -170,6 +171,8 @@ class _Handle(CompletionHandle):
         # digest -> PayloadSource, pinned while in flight so ("need", digest)
         # backfills can always be served
         self.sources: dict = task.payload_sources
+        #: span name -> (start_ns, end_ns) on the driver (tracing.file)
+        self.stamps: dict = {}
 
 
 @dataclasses.dataclass
@@ -981,6 +984,7 @@ class ClusterBackend(EventWaitMixin, Backend):
                 self._on_dead(w, "could not register handshaken socket")
 
     def _pump(self, w: _SockWorker) -> None:
+        received_ns = time.perf_counter_ns()
         try:
             frames = w.reader.feed()
         except Exception as exc:                     # noqa: BLE001
@@ -995,6 +999,8 @@ class ClusterBackend(EventWaitMixin, Backend):
             tag = frame[0]
             if tag == "hello":
                 w.meta = frame[1]
+                if tracing.ENABLED and "spans" in w.meta:
+                    tracing.file(None, None, w.meta.pop("spans"))
                 with self._pool_cv:
                     w.proc = self._match_pending_locked(w.meta)
                     w.ready = True
@@ -1100,8 +1106,12 @@ class ClusterBackend(EventWaitMixin, Backend):
                                              label=h.task.label)
                             self._track_remote(rv)
                             run = dataclasses.replace(run, value=rv)
+                        if run.spans is not None and "spans" in w.meta:
+                            # the worker's start-up, with its first
+                            # traced result
+                            tracing.file(None, None, w.meta.pop("spans"))
                         h.run = run
-                        self._finish(w, h)
+                        self._finish(w, h, received_ns)
             elif tag == "offer":
                 # answer to a driver-side ("fetch", digest): hand the blob
                 # to every puller parked on (wid, digest)
@@ -1167,7 +1177,10 @@ class ClusterBackend(EventWaitMixin, Backend):
                     return wp
         return None
 
-    def _finish(self, w: _SockWorker, h: _Handle) -> None:
+    def _finish(self, w: _SockWorker, h: _Handle,
+                received_ns: int = 0) -> None:
+        """Return ``w`` to the pool and push ``h``'s completion;
+        ``received_ns`` is when the driver began reading its result."""
         w.busy = None
         if h.cancelled:
             # cancel() already began killing this worker; don't reuse it.
@@ -1198,6 +1211,9 @@ class ClusterBackend(EventWaitMixin, Backend):
                     pass
             elif retire:
                 self._retire(w)
+        if received_ns:
+            h.stamps["cluster.result"] = (received_ns,
+                                          time.perf_counter_ns())
         # push completion from the select loop: done-callbacks (continuation
         # dispatch, cross-backend Waiter wake-ups) fire here
         self._complete(h)
@@ -1858,6 +1874,7 @@ class ClusterBackend(EventWaitMixin, Backend):
 
     def _dispatch(self, task: TaskSpec, worker: _SockWorker,
                   handle: "_Handle | None" = None) -> _Handle:
+        t0 = time.perf_counter_ns()
         if handle is None:
             handle = _Handle(task)
         blob = task.shipped
@@ -1905,6 +1922,7 @@ class ClusterBackend(EventWaitMixin, Backend):
                                ("task", task.task_id, blob, task.refs,
                                 hints, self._remote_results),
                                worker.send_lock)
+            handle.stamps["cluster.dispatch"] = (t0, time.perf_counter_ns())
             if task.tenant is not None:
                 with self._pool_cv:
                     self._tenant_rt_for_locked(

@@ -90,6 +90,7 @@ import socket
 import threading
 import time
 
+from .. import tracing
 from ..errors import ChannelError
 from .launchers import CHIP_ENV
 from .transport import (TLSConfig, become_executor, client_tls_context,
@@ -256,10 +257,13 @@ def claim_chip() -> "dict | None":
 def _serve(sock: socket.socket, *, tag: str = "",
            max_idle_s: float = 0.0,
            handshake_timeout: float = 30.0,
-           device: "dict | None" = None) -> str:
+           device: "dict | None" = None,
+           startup: "tuple" = (), dial_ns: int = 0) -> str:
     """Serve one driver connection until it ends; returns why:
     ``"stop"`` (stop frame), ``"idle"`` (``max_idle_s`` with no driver
-    frames), or ``"eof"`` (connection lost / driver died)."""
+    frames), or ``"eof"`` (connection lost / driver died). The hello
+    carries the start-up spans ``startup`` (``(name, start_ns,
+    end_ns)``) and ``worker.dial``, from ``dial_ns`` to the hello."""
     # the init frame must arrive promptly — a peer that accepted but never
     # serves (driver host crashed post-accept, port squatted by another
     # service) must not hang us forever before the idle watchdog even
@@ -379,7 +383,12 @@ def _serve(sock: socket.socket, *, tag: str = "",
         meta["peer"] = peer_srv.addr
     if device is not None:
         meta["device"] = device
+    meta["spans"] = tracing.pack(
+        [*startup, ("worker.dial", dial_ns, time.perf_counter_ns())]
+        if dial_ns else startup)
     send_frame(sock, ("hello", meta), send_lock)
+    phases = tracing.Phases()
+    phases.enter("worker.await_task")
 
     # One reader thread owns every read on the driver socket: it serves
     # ("fetch", digest) frames inline — so this worker keeps offering its
@@ -463,6 +472,7 @@ def _serve(sock: socket.socket, *, tag: str = "",
             if msg[0] != "task":
                 continue
             task_id, blob = msg[1], msg[2]
+            phases.enter("worker.decode", task_id)
             refs = msg[3] if len(msg) > 3 else ()
             hints = msg[4] if len(msg) > 4 else None
             keep = bool(msg[5]) if len(msg) > 5 else False
@@ -497,7 +507,8 @@ def _serve(sock: socket.socket, *, tag: str = "",
                     with state_context(st_client):
                         run = execute_shipped(
                             blob, emit,
-                            resolve_ref=lambda r: store.resolve(r.digest))
+                            resolve_ref=lambda r: store.resolve(r.digest),
+                            phases=phases)
             except (EOFError, OSError):
                 return _reason()
             except Exception as exc:                 # noqa: BLE001
@@ -512,11 +523,15 @@ def _serve(sock: socket.socket, *, tag: str = "",
             held = ()
             if keep:
                 run, held = hold_result(store, run)
+            phases.enter("worker.send")
+            run.spans = phases.take()
             try:
                 send_frame(sock, ("result", task_id, run, held), send_lock)
             except OSError:
                 return _reason()
+            phases.enter("worker.await_task")
     finally:
+        phases.enter(None)
         stop.set()
         peer_srv.close()
         try:
@@ -553,7 +568,8 @@ def run_worker(host: str, port: int, *, connect_timeout: float = 30.0,
                tag: str = "", reconnect: bool = False,
                max_idle_s: float = 0.0, token: str = "",
                tls: "TLSConfig | None" = None,
-               device: "dict | None" = None) -> None:
+               device: "dict | None" = None,
+               claimed: "tuple[int, int] | None" = None) -> None:
     """Connect to the driver and resolve shipped futures until told to stop
     or the connection drops. Default: exit on disconnect and let the
     driver's relaunch policy self-heal; with ``reconnect=True`` keep
@@ -561,7 +577,10 @@ def run_worker(host: str, port: int, *, connect_timeout: float = 30.0,
     ``token``/``tls`` must match the driver's security settings (launched
     workers inherit them via ``REPRO_CLUSTER_TOKEN`` / ``REPRO_CLUSTER_TLS``
     / ``REPRO_CLUSTER_TLS_CA``). ``device`` is the chip this worker
-    claimed (:func:`claim_chip`), reported in its hello."""
+    claimed (:func:`claim_chip`), reported in its hello with the
+    ``(start_ns, end_ns)`` the claim took (``claimed``) and the time from
+    dialing the driver to the hello (spans ``worker.claim_chip`` and
+    ``worker.dial``, :mod:`repro.core.tracing`)."""
     os.environ.setdefault("OMP_NUM_THREADS", "1")
     os.environ.setdefault("XLA_FLAGS", "--xla_cpu_multi_thread_eigen=false")
 
@@ -574,6 +593,7 @@ def run_worker(host: str, port: int, *, connect_timeout: float = 30.0,
         if reconnect and max_idle_s \
                 and time.monotonic() - useful_at > max_idle_s:
             return
+        dial_ns = time.perf_counter_ns()
         try:
             sock = socket.create_connection((host, port),
                                             timeout=connect_timeout)
@@ -589,7 +609,9 @@ def run_worker(host: str, port: int, *, connect_timeout: float = 30.0,
                                 timeout=connect_timeout)
             reason = _serve(sock, tag=tag, max_idle_s=max_idle_s,
                             handshake_timeout=connect_timeout,
-                            device=device)
+                            device=device, dial_ns=dial_ns,
+                            startup=(("worker.claim_chip", *claimed),)
+                            if claimed is not None else ())
         except (EOFError, ChannelError, OSError):
             # connection lost inside the init/security handshake (driver
             # mid-restart accepted then closed, credential mismatch): same
@@ -654,10 +676,13 @@ def main(argv=None) -> None:
     from ...cache import enable_compile_cache
     enable_compile_cache()
     become_executor()
+    t0 = time.perf_counter_ns()
+    device = claim_chip()
     run_worker(host or "127.0.0.1", int(port),
                connect_timeout=args.connect_timeout, tag=args.tag,
                reconnect=args.reconnect, max_idle_s=args.max_idle_s,
-               token=args.token, tls=tls, device=claim_chip())
+               token=args.token, tls=tls, device=device,
+               claimed=(t0, time.perf_counter_ns()))
 
 
 if __name__ == "__main__":

@@ -54,11 +54,15 @@ def _sanitize_run(run) -> Any:
     return run
 
 
-def execute_shipped(blob: bytes, emit, resolve_ref=None) -> Any:
+def execute_shipped(blob: bytes, emit, resolve_ref=None,
+                    phases=None) -> Any:
     """Resolve one shipped task blob: unship the function (content-addressed
     globals resolved through ``resolve_ref``), evaluate under capture_run,
     sanitize for the trip home. Shared by the pipe (processes) and socket
-    (cluster) workers so relay/error behaviour is identical."""
+    (cluster) workers so relay/error behaviour is identical. ``phases``
+    (the loop's :class:`~repro.core.tracing.Phases`) enters
+    ``worker.run`` around the function's call and ``worker.encode``
+    after it."""
     import contextlib
 
     from ..conditions import capture_run
@@ -72,9 +76,19 @@ def execute_shipped(blob: bytes, emit, resolve_ref=None) -> Any:
         # their PayloadRefs through the ambient resolver
         payload = pickle.loads(blob)
         fn = unship_function(payload["fn"], resolve_ref=resolve_ref)
+
+    def call():
+        if phases is None:
+            return rng_call(fn, payload["args"], payload["kwargs"])
+        phases.enter("worker.run")
+        try:
+            return rng_call(fn, payload["args"], payload["kwargs"])
+        finally:
+            phases.enter("worker.encode")
+
     with rng_scope(payload["seed_declared"]):
         run = capture_run(
-            lambda: rng_call(fn, payload["args"], payload["kwargs"]),
+            call,
             capture_stdout=payload["capture_stdout"],
             capture_conditions=payload["capture_conditions"],
             immediate_emit=emit,
@@ -185,6 +199,7 @@ def worker_main(conn, nested_stack_blob: bytes, session_seed: int,
 
     from .. import planning as plan_mod
     from .. import rng as rng_mod
+    from .. import tracing
     from ..errors import ChannelError
     from ..state import PipeStateClient, state_context
     from .transport import become_executor
@@ -200,6 +215,8 @@ def worker_main(conn, nested_stack_blob: bytes, session_seed: int,
     # thread is the pipe's only reader, and only calls while inside a task
     st_client = PipeStateClient(conn, store=store)
     conn.send(("ready",))
+    phases = tracing.Phases()
+    phases.enter("worker.await_task")
     while True:
         try:
             msg = conn.recv()
@@ -214,6 +231,7 @@ def worker_main(conn, nested_stack_blob: bytes, session_seed: int,
             continue
         task_id, blob = msg[1], msg[2]
         refs = msg[3] if len(msg) > 3 else ()
+        phases.enter("worker.decode", task_id)
 
         def emit(cond, _tid=task_id):
             try:
@@ -233,12 +251,16 @@ def worker_main(conn, nested_stack_blob: bytes, session_seed: int,
                 with state_context(st_client):
                     run = execute_shipped(
                         blob, emit,
-                        resolve_ref=lambda r: store.resolve(r.digest))
+                        resolve_ref=lambda r: store.resolve(r.digest),
+                        phases=phases)
         except (EOFError, OSError):
             return                           # channel gone mid-backfill
         except ChannelError as exc:
             run = error_run(exc)
+        phases.enter("worker.send")
+        run.spans = phases.take()
         try:
             conn.send(("result", task_id, run))
         except (OSError, ValueError):
             return
+        phases.enter("worker.await_task")

@@ -109,7 +109,9 @@ class CapturedRun:
     stdout: str = ""
     conditions: list[Condition] = dataclasses.field(default_factory=list)
     immediate: list[ImmediateCondition] = dataclasses.field(default_factory=list)
-    wall_time_s: float = 0.0
+    #: spans a worker recorded, sent home with the result
+    #: (``repro.core.tracing``); None when nothing was recorded
+    spans: list | None = None
     rng_touched: bool = False
 
 
@@ -188,7 +190,6 @@ def capture_run(fn: Callable[[], Any], *,
     conformance requirement).
     """
     run = CapturedRun()
-    t0 = time.time()
 
     prev_sink_emit, prev_sink_coll = _SINK.emit, _SINK.collected
     if immediate_emit is not None:
@@ -243,7 +244,6 @@ def capture_run(fn: Callable[[], Any], *,
         _SINK.emit, _SINK.collected = prev_sink_emit, prev_sink_coll
 
     run.stdout = out_buf.getvalue()
-    run.wall_time_s = time.time() - t0
     return run
 
 

@@ -64,6 +64,7 @@ from .errors import (FutureCancelledError, FutureError, GlobalsError,
                      WorkerDiedError)
 from .globals_capture import identify_globals, ship_function
 from . import rng as rng_mod
+from . import tracing
 
 _ids = itertools.count(1)
 
@@ -305,6 +306,10 @@ def _outcome(f: "Future") -> "tuple[CapturedRun | None, Exception | None]":
         return None, exc
 
 
+def _join_spans(a: "list | None", b: "list | None") -> "list | None":
+    return a + b if a and b else a or b
+
+
 def _merge_runs(head: CapturedRun, tail: CapturedRun) -> CapturedRun:
     """Value/error from ``tail``; captures concatenated, so one ``value()``
     on a chained future relays the whole chain's output in order."""
@@ -313,7 +318,7 @@ def _merge_runs(head: CapturedRun, tail: CapturedRun) -> CapturedRun:
         stdout=head.stdout + tail.stdout,
         conditions=head.conditions + tail.conditions,
         immediate=head.immediate + tail.immediate,
-        wall_time_s=head.wall_time_s + tail.wall_time_s,
+        spans=_join_spans(head.spans, tail.spans),
         rng_touched=head.rng_touched or tail.rng_touched)
 
 
@@ -330,6 +335,7 @@ class Future:
                  stdout: bool = True,
                  conditions: bool = True,
                  backend: Backend | None = None):
+        self._created_ns = time.perf_counter_ns()
         self.id = next(_ids)
         self.label = label or f"future-{self.id}"
         self._lock = threading.Lock()
@@ -340,6 +346,8 @@ class Future:
         self._stdout = stdout
         self._conditions = conditions
         self._backend = backend
+        #: span name -> (start_ns, end_ns) of this future's driver side
+        self._stamps: dict = {}
 
         self.seed_declared = seed is not None and seed is not False
         if seed is False:
@@ -376,6 +384,7 @@ class Future:
         f._stdout = True
         f._conditions = True
         f._backend = _CHAIN
+        f._stamps = {}
         f.seed_declared = False
         f._stream_index = None                   # no RNG stream consumed
         f._snapshot, f._packages = {}, set()
@@ -393,6 +402,7 @@ class Future:
             # as PayloadRef digests (shipped at most once per worker); the
             # extraction doubles as the exportability scan, raising
             # NonExportableObjectError at creation like assert_exportable.
+            t0 = time.perf_counter_ns()
             from .globals_capture import (dumps_robust,
                                           extract_call_refs,
                                           extract_payload_refs)
@@ -414,6 +424,7 @@ class Future:
                 "capture_conditions": self._conditions,
                 "seed_declared": self.seed_declared,
             }, ref_sink=sources)
+            self._stamps["future.ship"] = (t0, time.perf_counter_ns())
         return TaskSpec(
             task_id=self.id, fn=self._fn, args=args,
             kwargs=kwargs, label=self.label,
@@ -498,7 +509,15 @@ class Future:
         assert self._run is not None
         if not self._relayed:
             self._relayed = True
-            return relay(self._run)          # prints, warns, raises, returns
+            t0 = time.perf_counter_ns()
+            try:
+                return relay(self._run)      # prints, warns, raises, returns
+            finally:
+                self._stamps["future.relay"] = (t0, time.perf_counter_ns())
+                if tracing.ENABLED or self._run.spans is not None:
+                    tracing.file(self.id, self.label, self._run.spans,
+                                 self._stamps,
+                                 getattr(self._handle, "stamps", None))
         if self._run.error is not None:
             raise self._run.error
         return self._run.value
@@ -1178,7 +1197,7 @@ def _step_gather(fs: list[Future], out: Future) -> None:
         merged.stdout += r.stdout
         merged.conditions = merged.conditions + r.conditions
         merged.immediate = merged.immediate + r.immediate
-        merged.wall_time_s += r.wall_time_s
+        merged.spans = _join_spans(merged.spans, r.spans)
         merged.rng_touched |= r.rng_touched
     for r in runs:
         if r.error is not None:

@@ -61,6 +61,7 @@ import asyncio
 import collections
 import dataclasses
 import itertools
+import time
 from typing import Any, AsyncIterator, Callable, Iterable, Iterator
 
 from . import planning as plan_mod
@@ -258,6 +259,8 @@ def _pump(op: _MapOp, upstream: Iterator, *, max_in_flight: "int | None",
                         break
                 else:
                     rec[0]._submit()
+                rec[0]._stamps["stream.queued"] = (rec[0]._created_ns,
+                                                   time.perf_counter_ns())
                 queue.popleft()
                 pending[rec[0]] = rec
                 waiter.add(rec[0])
@@ -441,6 +444,8 @@ async def _pump_async(op: _MapOp, upstream: AsyncIterator, *,
                 else:
                     while not rec[0]._submit_nowait():
                         await asyncio.sleep(_CONTENTION_WAIT_S)
+                rec[0]._stamps["stream.queued"] = (rec[0]._created_ns,
+                                                   time.perf_counter_ns())
                 queue.popleft()
                 pending[rec[0]] = rec
                 waiter.add(rec[0])

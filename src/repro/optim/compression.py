@@ -9,8 +9,7 @@ cross-pod gradient all-reduce is the collective to compress:
   re-injected next step (EF-SGD; keeps convergence), on the host.
 * :func:`topk_sparsify` — magnitude top-k with index+value encoding.
 
-Used by the multi-pod launcher (launch/train.py) and benchmarked in
-benchmarks/bench_compression.py.
+Used by the multi-pod launcher (launch/train.py).
 """
 
 from __future__ import annotations
