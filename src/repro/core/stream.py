@@ -31,7 +31,9 @@ Mechanics of the pump (one per ``.map`` stage):
   one blocking ``submit`` (progress guarantee — the paper's "future()
   blocks until a worker is available" semantics, but only at the edge);
 * completions are push-delivered through one :class:`~.future.Waiter`;
-  the pump harvests, re-dispatches ``retries=`` failed chunks
+  a completion frees its slot before it wakes the pump, so the pump first
+  submits the queued chunk that waited for that slot, and only then
+  harvests, re-dispatches ``retries=`` failed chunks
   (``FutureError`` only — evaluation errors propagate, like
   ``future_map``), and refills from upstream. On the cluster backend a
   chunk whose worker-resident result was *lost* (holder death, eviction
@@ -51,7 +53,9 @@ Mechanics of the pump (one per ``.map`` stage):
 ``Stream`` objects are immutable — each combinator returns a new stream
 sharing the source. A stream over a one-shot iterator is single-use.
 After a terminal runs, ``.stats`` on the terminal stream records
-``dispatched`` / ``retried`` chunk counts and ``peak_in_flight`` (always
+``dispatched`` / ``retried`` chunk counts, ``dispatched_on_completion``
+(the chunks submitted as a completion woke the pump, before its harvest)
+and ``peak_in_flight`` (submitted and not yet completed; always
 ``<= max_in_flight`` — asserted by the conformance suite).
 """
 
@@ -210,6 +214,34 @@ def _pump(op: _MapOp, upstream: Iterator, *, max_in_flight: "int | None",
     done_buf: "dict[int, list]" = {}   # cid -> values (ordered mode)
     emit: "collections.deque" = collections.deque()   # values (unordered)
     waiter = Waiter()
+
+    def dispatch(settled: int = 0) -> int:
+        """Submit the head of ``queue`` until the backend refuses, exactly
+        when capacity exists; one blocking submit only when nothing is in
+        flight (progress guarantee — nothing else would wake us).
+        ``settled`` futures of ``pending`` have completed and await their
+        harvest: they are not in flight. Returns how many went."""
+        n = 0
+        while queue:
+            rec = queue[0]
+            if pending:
+                if not rec[0]._submit_nowait():
+                    break
+            else:
+                rec[0]._submit()
+            rec[0]._stamps["stream.queued"] = (rec[0]._created_ns,
+                                               time.perf_counter_ns())
+            queue.popleft()
+            pending[rec[0]] = rec
+            waiter.add(rec[0])
+            n += 1
+            stats["dispatched"] = stats.get("dispatched", 0) + 1
+            stats["peak_in_flight"] = max(
+                stats.get("peak_in_flight", 0), len(pending) - settled)
+            stats["peak_in_flight_bytes"] = max(
+                stats.get("peak_in_flight_bytes", 0), in_bytes)
+        return n
+
     src_done = False
     cid_seq = 0
     emit_id = 0
@@ -247,37 +279,22 @@ def _pump(op: _MapOp, upstream: Iterator, *, max_in_flight: "int | None",
                 queue.append((make(cid_seq, idx, items, 0),
                               cid_seq, idx, items, 0, nbytes))
                 cid_seq += 1
-            # 3. admission-controlled dispatch: exactly when capacity
-            #    exists; one blocking submit only when nothing is in
-            #    flight (progress guarantee — nothing else would wake us)
-            contended = False
-            while queue:
-                rec = queue[0]
-                if pending:
-                    if not rec[0]._submit_nowait():
-                        contended = True
-                        break
-                else:
-                    rec[0]._submit()
-                rec[0]._stamps["stream.queued"] = (rec[0]._created_ns,
-                                                   time.perf_counter_ns())
-                queue.popleft()
-                pending[rec[0]] = rec
-                waiter.add(rec[0])
-                stats["dispatched"] = stats.get("dispatched", 0) + 1
-                stats["peak_in_flight"] = max(
-                    stats.get("peak_in_flight", 0), len(pending))
-                stats["peak_in_flight_bytes"] = max(
-                    stats.get("peak_in_flight_bytes", 0), in_bytes)
+            # 3. admission-controlled dispatch
+            dispatch()
             if not pending:
                 if src_done and not queue and not done_buf and not emit:
                     return
                 continue
             # 4. sleep until a completion pushes (briefly, when foreign
             #    futures hold the slots we were refused)
-            got = waiter.wait(_CONTENTION_WAIT_S
-                              if contended and queue else None)
-            # 5. harvest in completion order (relays stdout/conditions,
+            got = waiter.wait(_CONTENTION_WAIT_S if queue else None)
+            # 5. the completions freed their slots: submit the waiting
+            #    head of the queue first, so that the relay, the yield and
+            #    the next future's creation run while it computes
+            if got:
+                stats["dispatched_on_completion"] = stats.get(
+                    "dispatched_on_completion", 0) + dispatch(len(got))
+            # 6. harvest in completion order (relays stdout/conditions,
             #    like future_map); FutureError -> bounded re-dispatch
             for f in got:
                 _, cid, idx, items, tries, nbytes = pending.pop(f)
@@ -400,6 +417,33 @@ async def _pump_async(op: _MapOp, upstream: AsyncIterator, *,
     done_buf: "dict[int, list]" = {}
     emit: "collections.deque" = collections.deque()
     waiter = AsyncWaiter()
+
+    async def dispatch(settled: int = 0) -> int:
+        """:func:`_pump`'s ``dispatch``, with the progress-guarantee
+        submit (nothing in flight) made a cooperative re-offer loop —
+        never park the event loop in ``submit()``."""
+        n = 0
+        while queue:
+            rec = queue[0]
+            if pending:
+                if not rec[0]._submit_nowait():
+                    break
+            else:
+                while not rec[0]._submit_nowait():
+                    await asyncio.sleep(_CONTENTION_WAIT_S)
+            rec[0]._stamps["stream.queued"] = (rec[0]._created_ns,
+                                               time.perf_counter_ns())
+            queue.popleft()
+            pending[rec[0]] = rec
+            waiter.add(rec[0])
+            n += 1
+            stats["dispatched"] = stats.get("dispatched", 0) + 1
+            stats["peak_in_flight"] = max(
+                stats.get("peak_in_flight", 0), len(pending) - settled)
+            stats["peak_in_flight_bytes"] = max(
+                stats.get("peak_in_flight_bytes", 0), in_bytes)
+        return n
+
     src_done = False
     cid_seq = 0
     emit_id = 0
@@ -431,37 +475,19 @@ async def _pump_async(op: _MapOp, upstream: AsyncIterator, *,
                 queue.append((make(cid_seq, idx, items, 0),
                               cid_seq, idx, items, 0, nbytes))
                 cid_seq += 1
-            # 3. admission-controlled dispatch; the progress-guarantee
-            #    submit (nothing in flight) becomes a cooperative
-            #    re-offer loop — never park the event loop in submit()
-            contended = False
-            while queue:
-                rec = queue[0]
-                if pending:
-                    if not rec[0]._submit_nowait():
-                        contended = True
-                        break
-                else:
-                    while not rec[0]._submit_nowait():
-                        await asyncio.sleep(_CONTENTION_WAIT_S)
-                rec[0]._stamps["stream.queued"] = (rec[0]._created_ns,
-                                                   time.perf_counter_ns())
-                queue.popleft()
-                pending[rec[0]] = rec
-                waiter.add(rec[0])
-                stats["dispatched"] = stats.get("dispatched", 0) + 1
-                stats["peak_in_flight"] = max(
-                    stats.get("peak_in_flight", 0), len(pending))
-                stats["peak_in_flight_bytes"] = max(
-                    stats.get("peak_in_flight_bytes", 0), in_bytes)
+            # 3. admission-controlled dispatch
+            await dispatch()
             if not pending:
                 if src_done and not queue and not done_buf and not emit:
                     return
                 continue
             # 4. suspend until a completion is marshalled into this loop
-            got = await waiter.wait(_CONTENTION_WAIT_S
-                                    if contended and queue else None)
-            # 5. harvest in completion order; FutureError -> re-dispatch
+            got = await waiter.wait(_CONTENTION_WAIT_S if queue else None)
+            # 5. submit the waiting head of the queue before the harvest
+            if got:
+                stats["dispatched_on_completion"] = stats.get(
+                    "dispatched_on_completion", 0) + await dispatch(len(got))
+            # 6. harvest in completion order; FutureError -> re-dispatch
             for f in got:
                 _, cid, idx, items, tries, nbytes = pending.pop(f)
                 try:
@@ -590,6 +616,7 @@ class Stream:
     def _run(self, ordered: bool) -> Iterator:
         self.stats.clear()
         self.stats.update({"dispatched": 0, "retried": 0,
+                           "dispatched_on_completion": 0,
                            "peak_in_flight": 0, "max_in_flight": None,
                            "peak_in_flight_bytes": 0,
                            "max_in_flight_bytes": None})
@@ -616,6 +643,7 @@ class Stream:
         onto the cooperative stages — run it from inside an event loop."""
         self.stats.clear()
         self.stats.update({"dispatched": 0, "retried": 0,
+                           "dispatched_on_completion": 0,
                            "peak_in_flight": 0, "max_in_flight": None,
                            "peak_in_flight_bytes": 0,
                            "max_in_flight_bytes": None})

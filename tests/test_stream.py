@@ -369,3 +369,151 @@ def test_max_in_flight_bytes_composes_with_count_bound():
     assert s.stats["peak_in_flight"] <= 3
     assert s.stats["peak_in_flight_bytes"] <= 1 << 20
     rc.shutdown()
+
+
+# --------------------------------------------------------------------------
+# completion-first dispatch: the chunk waiting in the queue is submitted as
+# soon as a completion frees its slot, before the pump relays that result
+# and creates the next future
+# --------------------------------------------------------------------------
+
+@pytest.fixture
+def traced(monkeypatch):
+    """The driver's stamps of every future land in the span ring."""
+    from repro.core import tracing
+    monkeypatch.setattr(tracing, "ENABLED", True)
+    tracing.reset()
+    yield tracing
+    tracing.reset()
+
+
+def _stamps_by_request(tracing, n: int) -> list:
+    """Span name -> span for requests ``stream.map0[0..n-1]``, in order."""
+    by: "dict[str, dict]" = {}
+    for s in tracing.spans():
+        if s.label and s.label.startswith("stream.map0["):
+            by.setdefault(s.label, {})[s.name] = s
+    return [by[f"stream.map0[{i}]"] for i in range(n)]
+
+
+def _nap(x):
+    import time as _t
+    _t.sleep(0.02)
+    return x
+
+
+def _run_stream(terminal: str, xs, **kw):
+    """One ``.map`` over ``xs`` through a sync or an async terminal;
+    returns ``(values, stats)``."""
+    import asyncio
+    s = stream(xs, **kw)
+    m = s.map(_nap)
+    if terminal == "as_completed":
+        return list(m.as_completed()), s.stats
+    if terminal == "collect":
+        return m.collect(), s.stats
+
+    async def drain():
+        if terminal == "as_completed_async":
+            return [v async for v in m.as_completed_async()]
+        return await m.collect_async()
+    return asyncio.run(drain()), s.stats
+
+
+_TERMINALS = ["as_completed", "collect", "as_completed_async",
+              "collect_async"]
+
+
+@pytest.mark.parametrize("terminal", _TERMINALS)
+def test_queued_chunk_dispatched_before_relay(traced, terminal):
+    """One worker, the default ``max_in_flight`` (2): a chunk always waits
+    in the queue when the running one completes. Every chunk but the
+    first is submitted on a completion, and each leaves the queue before
+    the previous chunk's result is relayed."""
+    rc.plan("threads", workers=1)
+    n = 8
+    try:
+        vals, stats = _run_stream(terminal, range(n))
+    finally:
+        rc.shutdown()
+    if terminal.startswith("collect"):
+        assert vals == list(range(n))
+    else:
+        assert sorted(vals) == list(range(n))
+    assert stats["max_in_flight"] == 2
+    assert stats["dispatched"] == n and stats["retried"] == 0
+    assert stats["dispatched_on_completion"] == n - 1
+    # one worker: one chunk in flight at a time; a completed chunk that
+    # awaits its harvest is not counted
+    assert stats["peak_in_flight"] == 1
+    reqs = _stamps_by_request(traced, n)
+    for a, b in zip(reqs, reqs[1:]):
+        assert b["stream.queued"].end_ns <= a["future.relay"].start_ns
+
+
+@pytest.mark.parametrize("terminal", _TERMINALS)
+def test_completion_dispatch_keeps_byte_budget(terminal):
+    """The byte budget still bounds admission when chunks are submitted
+    on completion: a budget of one element keeps one chunk admitted."""
+    import numpy as np
+    rc.plan("threads", workers=1)
+    arrs = [np.full(1 << 12, i) for i in range(6)]      # 32 KiB each
+    try:
+        vals, stats = _run_stream(terminal, arrs,
+                                  max_in_flight_bytes=arrs[0].nbytes)
+    finally:
+        rc.shutdown()
+    assert sorted(int(v[0]) for v in vals) == list(range(6))
+    assert stats["peak_in_flight"] == 1
+    assert stats["peak_in_flight_bytes"] == arrs[0].nbytes
+    # nothing is ever queued behind the running chunk
+    assert stats["dispatched_on_completion"] == 0
+
+
+def test_completion_dispatch_with_retry_processes(tmp_path):
+    """A chunk whose worker dies is retried and the stream stays ordered
+    while the queued chunks are submitted on completion."""
+    rc.plan("processes", workers=1)
+    marker = str(tmp_path / "chunk-died")
+
+    def elem(x, _marker=marker):
+        import os as _os
+        import time as _t
+        if x == 2 and not _os.path.exists(_marker):
+            open(_marker, "w").close()
+            _os._exit(7)
+        _t.sleep(0.02)
+        return x * 2
+
+    s = stream(range(6))
+    try:
+        assert s.map(elem, retries=1).collect() == [0, 2, 4, 6, 8, 10]
+    finally:
+        rc.shutdown()
+    assert s.stats["retried"] == 1
+    assert s.stats["dispatched"] == 7
+    assert s.stats["dispatched_on_completion"] >= 1
+    assert s.stats["peak_in_flight"] == 1
+
+
+def test_cluster_dispatches_next_task_before_relay(traced):
+    """On one cluster worker, the next task's frames are sent before the
+    driver relays the previous result: relay and the next future's
+    creation are off the worker's critical path."""
+    rc.plan("cluster", workers=1)
+    n = 6
+
+    def nap(x):                  # shipped by value to the worker
+        import time as _t
+        _t.sleep(0.02)
+        return x
+
+    try:
+        assert list(stream(range(n)).map(nap).as_completed()) \
+            == list(range(n))
+    finally:
+        rc.shutdown()
+    reqs = _stamps_by_request(traced, n)
+    for a, b in zip(reqs, reqs[1:]):
+        assert b["cluster.dispatch"].end_ns <= a["future.relay"].start_ns
+        assert a["cluster.result"].end_ns <= b["cluster.dispatch"].start_ns
