@@ -166,7 +166,8 @@ def _ensure_loaded():
     global _loaded
     if _loaded:
         return
-    from . import (deepseek_moe_16b, hubert_xlarge, minicpm3_4b,  # noqa: F401
-                   nemotron_4_340b, qwen2_moe_a2_7b, qwen2_vl_72b,
-                   recurrentgemma_9b, xlstm_125m, yi_9b, yi_34b)
+    from . import (deepseek_moe_16b, deepseek_v2_lite,  # noqa: F401
+                   hubert_xlarge, minicpm3_4b, nemotron_4_340b,
+                   qwen2_moe_a2_7b, qwen2_vl_72b, recurrentgemma_9b,
+                   xlstm_125m, yi_9b, yi_34b)
     _loaded = True

@@ -37,6 +37,13 @@ Three kinds of sites feed it:
   always by a cluster worker and sent in its hello; the driver files them
   with the worker's first traced result, or at the hello when on.
 
+**Counters** are named values a task body records with :func:`count`
+(numbers, or nested lists of them), under the same switch as a worker's
+phases: on when tracing is on or a profiler session is live
+(:func:`recording`), off otherwise, and then nothing is kept. They go
+home with the task's phases in its result frame, are filed with its spans
+under its id, and :func:`counters` reads them.
+
 This module never imports ``jax``: the driver must not initialise a JAX
 backend, and a worker without ``jax`` never pays for it.
 """
@@ -81,6 +88,15 @@ _CROSS = frozenset({"worker.decode", "cluster.result"})
 PROC: "tuple[int, str]" = (os.getpid(), socket.gethostname())
 
 
+class Counter(NamedTuple):
+    task_id: "int | None"        # the future's id
+    name: str
+    value: object                # a number, or a nested list of numbers
+    pid: int
+    host: str
+    label: "str | None"          # the future's label, where it was filed
+
+
 class Span(NamedTuple):
     task_id: "int | None"        # the future's id; None for start-up
     name: str
@@ -94,8 +110,12 @@ class Span(NamedTuple):
 
 _lock = threading.Lock()
 _ring: "collections.deque" = collections.deque(maxlen=RING_SPANS)
+_counters: "collections.deque" = collections.deque(maxlen=RING_SPANS)
 _labels: "dict[int, str]" = {}
 _dropped = 0
+#: counters recorded in this process and not yet sent home (bounded: a
+#: process with no worker loop never sends them)
+_pending_counts: "collections.deque" = collections.deque(maxlen=1024)
 
 
 def enable(on: bool = True) -> None:
@@ -118,6 +138,29 @@ def spans() -> "list[Span]":
             for tid, name, parent, s, e, proc in rows]
 
 
+def counters() -> "list[Counter]":
+    """The counters filed in this process, oldest first."""
+    with _lock:
+        rows = list(_counters)
+        labels = dict(_labels)
+    return [Counter(tid, name, v, proc[0], proc[1], labels.get(tid))
+            for tid, name, v, proc in rows]
+
+
+def recording() -> bool:
+    """Whether :func:`count` keeps what it is given: tracing is on, or a
+    profiler session is live in this process."""
+    return ENABLED or _profiling()
+
+
+def count(name: str, value) -> None:
+    """Record counter ``name`` for the task running in this process; it
+    goes home with the task's phases. Nothing is kept unless
+    :func:`recording`."""
+    if recording():
+        _pending_counts.append((name, value))
+
+
 def dropped() -> int:
     """Spans the ring let go since :func:`reset`."""
     return _dropped
@@ -128,11 +171,12 @@ def reset() -> None:
     global _dropped
     with _lock:
         _ring.clear()
+        _counters.clear()
         _labels.clear()
         _dropped = 0
 
 
-def _put(rows: "list[tuple]", task_id: "int | None",
+def _put(rows: "list[tuple]", counts: "list[tuple]", task_id: "int | None",
          label: "str | None") -> None:
     global _dropped
     with _lock:
@@ -140,6 +184,7 @@ def _put(rows: "list[tuple]", task_id: "int | None",
         if over > 0:
             _dropped += over
         _ring.extend(rows)
+        _counters.extend(counts)
         if label is not None and task_id is not None:
             _labels[task_id] = label
             if len(_labels) > RING_SPANS:
@@ -151,7 +196,8 @@ def file(task_id: "int | None", label: "str | None", remote,
     """Put one future's spans in this process's ring: each of ``stamps``
     maps span names to ``(start_ns, end_ns)`` taken in this process;
     ``remote`` is what came home with the result (:meth:`Phases.take`'s
-    packs, or a hello's), whose spans keep their own task ids.
+    packs, or a hello's), whose spans and counters keep their own task
+    ids.
 
     Stamps of another process are joined to this one's on the shared
     monotonic clock only when it reports this host: the cross-process
@@ -166,14 +212,19 @@ def file(task_id: "int | None", label: "str | None", remote,
             if not joined and name in _CROSS:
                 parent = None
             rows.append((task_id, name, parent, s, e, PROC))
+    counts = []
     for proc, items in remote or ():
         same = proc[1] == PROC[1]
-        for tid, name, s, e in items:
+        for item in items:
+            if len(item) == 3:                       # a counter
+                counts.append((*item, proc))
+                continue
+            tid, name, s, e = item
             parent = PARENT.get(name)
             if not same and name in _CROSS:
                 parent = None
             rows.append((tid, name, parent, s, e, proc))
-    _put(rows, task_id, label)
+    _put(rows, counts, task_id, label)
 
 
 def pack(items: "Iterable[tuple[str, int, int]]") -> list:
@@ -260,8 +311,13 @@ class Phases:
                           else self._task, t, ann)
 
     def take(self) -> "list | None":
-        """The phases closed since the last call, packed for the result
-        frame (``None`` when there are none)."""
+        """The phases closed since the last call and the counters
+        recorded since then, packed for the result frame as the task's
+        (``None`` when there are none)."""
+        if _pending_counts:
+            counts = [(self._task, name, v) for name, v in _pending_counts]
+            _pending_counts.clear()
+            self._pending = (self._pending or []) + counts
         if self._pending is None:
             return None
         out, self._pending = [(PROC, self._pending)], None

@@ -9,6 +9,7 @@ models do not call them.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -60,15 +61,69 @@ def rope_freqs(head_dim: int, theta: float = 1e6) -> jax.Array:
     return 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
 
 
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 configures it
+    (``rope_scaling`` of type ``yarn``)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def get_mscale(factor: float, mscale: float) -> float:
+        return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+    def freqs(self, head_dim: int, theta: float) -> np.ndarray:
+        """Inverse frequencies: the base ones on the fast dimensions, the
+        interpolated ones (divided by ``factor``) on the slow, and a linear
+        ramp between the correction range's ends."""
+        def dim_of(rotations):
+            return head_dim * math.log(
+                self.original_max_position / (rotations * 2 * math.pi)) \
+                / (2 * math.log(theta))
+        low = max(math.floor(dim_of(self.beta_fast)), 0)
+        high = min(math.ceil(dim_of(self.beta_slow)), head_dim - 1)
+        base = rope_freqs(head_dim, theta)
+        span = high - low if high != low else 0.001
+        ramp = np.clip((np.arange(head_dim // 2) - low) / span, 0, 1)
+        return base / self.factor * ramp + base * (1 - ramp)
+
+    @property
+    def cos_sin_scale(self) -> float:
+        return self.get_mscale(self.factor, self.mscale) \
+            / self.get_mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def softmax_scale(self) -> float:
+        """What the attention logits' ``head_dim ** -0.5`` is multiplied by."""
+        if not self.mscale_all_dim:
+            return 1.0
+        return self.get_mscale(self.factor, self.mscale_all_dim) ** 2
+
+
 def apply_rope(x: jax.Array, positions: jax.Array,
-               theta: float = 1e6) -> jax.Array:
-    """x: (B, S, H, D); positions: (B, S) int32."""
+               theta: float = 1e6, *, yarn: "Yarn | None" = None,
+               interleaved: bool = False) -> jax.Array:
+    """x: (B, S, H, D); positions: (B, S) int32. ``interleaved`` rotates
+    the pairs (x[2i], x[2i+1]) and returns them de-interleaved (first
+    members, then second members), as DeepSeek-V2 does; by default the
+    pairs are (x[i], x[i + D/2])."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                                # (D/2,)
+    freqs = rope_freqs(d, theta) if yarn is None \
+        else yarn.freqs(d, theta)                               # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs      # (B,S,D/2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    if yarn is not None and yarn.cos_sin_scale != 1.0:
+        cos, sin = cos * yarn.cos_sin_scale, sin * yarn.cos_sin_scale
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    else:
+        x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return out.astype(x.dtype)
 
@@ -122,7 +177,8 @@ def attention_init(key, dims: AttnDims, dtype=jnp.float32,
 
 def sdpa(q: jax.Array, k: jax.Array, v: jax.Array, *,
          causal: bool, window: int | None = None,
-         q_offset: int = 0, kv_len: jax.Array | None = None) -> jax.Array:
+         q_offset: int = 0, kv_len: jax.Array | None = None,
+         scale: float | None = None) -> jax.Array:
     """Grouped softmax attention. q: (B,Sq,H,D), k/v: (B,Skv,KV,D) with
     H = KV * G — KV heads are *never* materialized G times (a 1/G memory
     saving over the naive repeat_kv formulation). fp32 softmax.
@@ -130,11 +186,13 @@ def sdpa(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``window``: local attention — key j visible to query i iff
     i - window < j <= i.  ``q_offset``: absolute position of q[0] (decode).
     ``kv_len``: optional (B,) active cache lengths (decode masking).
+    ``scale``: what the logits are multiplied by (default ``D ** -0.5``).
     """
     b, sq, h, d = q.shape
     skv, kvh = k.shape[1], k.shape[2]
     g = h // kvh
-    scale = d ** -0.5
+    if scale is None:
+        scale = d ** -0.5
     qg = q.reshape(b, sq, kvh, g, d)
     logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
                         preferred_element_type=jnp.float32) * scale

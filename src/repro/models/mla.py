@@ -2,28 +2,39 @@
 
 KV is compressed into a low-rank latent c_kv (d_c) plus a shared rotary key
 k_rope; the decode cache stores only (c_kv, k_rope) — the paper-family's
-memory saving. Queries come from their own low-rank latent.
+memory saving. Queries come from their own low-rank latent, or straight
+from x where ``q_lora_rank`` is ``None`` (DeepSeek-V2-Lite).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 
-from .layers import Params, _he, apply_rope, rmsnorm, rmsnorm_init, sdpa
+from .layers import (Params, Yarn, _he, apply_rope, rmsnorm, rmsnorm_init,
+                     sdpa)
 
 
 @dataclasses.dataclass(frozen=True)
 class MLADims:
     d_model: int
     n_heads: int
-    q_lora_rank: int = 768
+    q_lora_rank: int | None = 768     # None: a direct query projection
     kv_lora_rank: int = 256
     qk_nope_dim: int = 64
     qk_rope_dim: int = 32
     v_head_dim: int = 64
+    # rotary pairs (x[2i], x[2i+1]) instead of (x[i], x[i + D/2])
+    rope_interleaved: bool = False
+    yarn: Yarn | None = None
+
+    @property
+    def softmax_scale(self) -> float:
+        s = (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
+        return s if self.yarn is None else s * self.yarn.softmax_scale
 
 
 def mla_init(key, dims: MLADims, dtype=jnp.float32) -> Params:
@@ -32,10 +43,14 @@ def mla_init(key, dims: MLADims, dtype=jnp.float32) -> Params:
     r_q, r_kv = dims.q_lora_rank, dims.kv_lora_rank
     dn, dr, dv = dims.qk_nope_dim, dims.qk_rope_dim, dims.v_head_dim
     s = d ** -0.5
+    if r_q is None:
+        q = {"wq": _he(ks[1], (d, h * (dn + dr)), s, dtype)}
+    else:
+        q = {"wq_a": _he(ks[0], (d, r_q), s, dtype),
+             "q_a_norm": rmsnorm_init(r_q, dtype),
+             "wq_b": _he(ks[1], (r_q, h * (dn + dr)), r_q ** -0.5, dtype)}
     return {
-        "wq_a": _he(ks[0], (d, r_q), s, dtype),
-        "q_a_norm": rmsnorm_init(r_q, dtype),
-        "wq_b": _he(ks[1], (r_q, h * (dn + dr)), r_q ** -0.5, dtype),
+        **q,
         "wkv_a": _he(ks[2], (d, r_kv + dr), s, dtype),
         "kv_a_norm": rmsnorm_init(r_kv, dtype),
         "wkv_b": _he(ks[3], (r_kv, h * (dn + dv)), r_kv ** -0.5, dtype),
@@ -43,6 +58,7 @@ def mla_init(key, dims: MLADims, dtype=jnp.float32) -> Params:
     }
 
 
+@jax.named_scope("mla")
 def mla_apply(p: Params, x: jax.Array, dims: MLADims, *,
               positions: jax.Array | None = None,
               cache: Params | None = None,
@@ -52,10 +68,17 @@ def mla_apply(p: Params, x: jax.Array, dims: MLADims, *,
     h = dims.n_heads
     dn, dr, dv = dims.qk_nope_dim, dims.qk_rope_dim, dims.v_head_dim
     r_kv = dims.kv_lora_rank
+    apply_rope_ = functools.partial(apply_rope, theta=rope_theta,
+                                    yarn=dims.yarn,
+                                    interleaved=dims.rope_interleaved)
 
-    q_lat = rmsnorm(p["q_a_norm"], jnp.einsum("bsd,dr->bsr", x, p["wq_a"]),
-                    norm_eps)
-    q = jnp.einsum("bsr,re->bse", q_lat, p["wq_b"]).reshape(b, s, h, dn + dr)
+    if "wq" in p:
+        q = jnp.einsum("bsd,de->bse", x, p["wq"])
+    else:
+        q_lat = rmsnorm(p["q_a_norm"],
+                        jnp.einsum("bsd,dr->bsr", x, p["wq_a"]), norm_eps)
+        q = jnp.einsum("bsr,re->bse", q_lat, p["wq_b"])
+    q = q.reshape(b, s, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
 
     kv_a = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])             # (B,S,r+dr)
@@ -69,8 +92,8 @@ def mla_apply(p: Params, x: jax.Array, dims: MLADims, *,
         # fold wkv_b into the query/output sides and attend directly in
         # the r-dim latent space — O(S*r*H) per token, no expansion.
         pos = cache["pos"]
-        q_rope = apply_rope(q_rope, pos[:, None], rope_theta)
-        k_rope = apply_rope(k_rope, pos[:, None], rope_theta)
+        q_rope = apply_rope_(q_rope, pos[:, None])
+        k_rope = apply_rope_(k_rope, pos[:, None])
         smax = cache["c_kv"].shape[1]
         bix = jnp.arange(b)
         new_ckv = cache["c_kv"].at[bix, pos].set(
@@ -85,7 +108,7 @@ def mla_apply(p: Params, x: jax.Array, dims: MLADims, *,
         scores = (jnp.einsum("bqhr,bsr->bhqs", q_abs, ckv_f)
                   + jnp.einsum("bqhd,bsd->bhqs", q_rope,
                                new_krope[:, :, 0].astype(x.dtype))) \
-            * ((dn + dr) ** -0.5)
+            * dims.softmax_scale
         valid = jnp.arange(smax)[None, :] < (pos + 1)[:, None]  # (B,S)
         scores = jnp.where(valid[:, None, None], scores.astype(jnp.float32),
                            -1e30)
@@ -97,15 +120,15 @@ def mla_apply(p: Params, x: jax.Array, dims: MLADims, *,
         if positions is None:
             positions = jnp.broadcast_to(
                 jnp.arange(s)[None, :].astype(jnp.int32), (b, s))
-        q_rope = apply_rope(q_rope, positions, rope_theta)
-        k_rope = apply_rope(k_rope, positions, rope_theta)
+        q_rope = apply_rope_(q_rope, positions)
+        k_rope = apply_rope_(k_rope, positions)
         kv = jnp.einsum("bsr,re->bse", c_kv, p["wkv_b"]) \
                 .reshape(b, s, h, dn + dv)
         k_nope, v = kv[..., :dn], kv[..., dn:]
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, (b, s, h, dr))], -1)
         qq = jnp.concatenate([q_nope, q_rope], -1)
-        out = sdpa(qq, k, v, causal=True)
+        out = sdpa(qq, k, v, causal=True, scale=dims.softmax_scale)
         new_cache = None
 
     out = out.reshape(b, s, h * dv)

@@ -10,6 +10,9 @@ Layer kinds ("blocks"):
   mlstm   self-contained mLSTM block (xLSTM)
   slstm   self-contained sLSTM block (xLSTM)
 
+The attention of attn, moe, dense and mla blocks is MLA where the config
+has MLA dims (MiniCPM3, DeepSeek-V2), and GQA otherwise.
+
 The stack is described by *stages*: ``(pattern, repeat)`` pairs. A stage
 with repeat>1 has its parameters stacked on a leading axis and is executed
 with ``jax.lax.scan`` so compile time and HLO size are depth-independent —
@@ -50,10 +53,12 @@ def block_init(key, cfg: ArchConfig, kind: str, dtype) -> Params:
     norm_init = (L.layernorm_init if cfg.norm == "layernorm"
                  else L.rmsnorm_init)
     d = cfg.d_model
-    if kind in ("attn", "lattn", "dense", "moe"):
-        p = {"ln1": norm_init(d, dtype),
-             "attn": L.attention_init(k1, _attn_dims(cfg, kind), dtype,
-                                      qk_norm=cfg.qk_norm),
+    if kind in ("attn", "lattn", "dense", "moe", "mla"):
+        assert kind != "mla" or cfg.mla is not None
+        attn = (MLA.mla_init(k1, cfg.mla, dtype) if cfg.mla is not None
+                else L.attention_init(k1, _attn_dims(cfg, kind), dtype,
+                                      qk_norm=cfg.qk_norm))
+        p = {"ln1": norm_init(d, dtype), "attn": attn,
              "ln2": norm_init(d, dtype)}
         if kind == "moe":
             assert cfg.moe is not None
@@ -64,12 +69,6 @@ def block_init(key, cfg: ArchConfig, kind: str, dtype) -> Params:
         else:
             p["mlp"] = L.mlp_init(k2, d, cfg.d_ff, cfg.mlp_kind, dtype)
         return p
-    if kind == "mla":
-        assert cfg.mla is not None
-        return {"ln1": norm_init(d, dtype),
-                "attn": MLA.mla_init(k1, cfg.mla, dtype),
-                "ln2": norm_init(d, dtype),
-                "mlp": L.mlp_init(k2, d, cfg.d_ff, cfg.mlp_kind, dtype)}
     if kind == "rglru":
         assert cfg.rglru is not None
         return {"ln1": norm_init(d, dtype),
@@ -94,55 +93,61 @@ def _norm(cfg: ArchConfig, p: Params, x):
 
 def block_apply(p: Params, x, cfg: ArchConfig, kind: str, *,
                 positions=None, cache=None, mesh=None):
-    """Returns (x_out, aux, new_cache)."""
+    """Returns (x_out, aux, new_cache, stats): ``stats`` maps names to
+    the block's counts (a held-experts MoE's, ``moe.moe_apply_held``),
+    empty for every other block."""
     aux = jnp.zeros((), jnp.float32)
-    if kind in ("attn", "lattn", "dense", "moe"):
-        window = cfg.attn_window if kind == "lattn" else None
-        h, new_cache = L.attention_apply(
-            p["attn"], _norm(cfg, p["ln1"], x), _attn_dims(cfg, kind),
-            positions=positions, rope_kind=cfg.rope_kind,
-            mrope_sections=cfg.mrope_sections, rope_theta=cfg.rope_theta,
-            causal=cfg.causal, window=window, cache=cache,
-            norm_eps=cfg.norm_eps, mesh=mesh)
+    if kind in ("attn", "lattn", "dense", "moe", "mla"):
+        xn = _norm(cfg, p["ln1"], x)
+        if cfg.mla is not None:
+            h, new_cache = MLA.mla_apply(
+                p["attn"], xn, cfg.mla, positions=positions, cache=cache,
+                rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps)
+        else:
+            window = cfg.attn_window if kind == "lattn" else None
+            h, new_cache = L.attention_apply(
+                p["attn"], xn, _attn_dims(cfg, kind),
+                positions=positions, rope_kind=cfg.rope_kind,
+                mrope_sections=cfg.mrope_sections,
+                rope_theta=cfg.rope_theta, causal=cfg.causal,
+                window=window, cache=cache, norm_eps=cfg.norm_eps,
+                mesh=mesh)
         x = x + h
         h2 = _norm(cfg, p["ln2"], x)
+        stats: dict = {}
         if kind == "moe":
             manual = (mesh is not None and "model" in mesh.axis_names
                       and cfg.moe.e_pad % mesh.shape["model"] == 0)
-            if manual:
+            if cfg.moe.experts_held is not None:
+                y, aux, stats = MOE.moe_apply_held(p["moe"], h2, cfg.moe)
+            elif manual:
                 y, aux = MOE.moe_apply_manual(p["moe"], h2, cfg.moe, mesh)
             else:
                 y, aux = MOE.moe_apply(p["moe"], h2, cfg.moe)
         else:
             y = L.mlp_apply(p["mlp"], h2, cfg.mlp_kind)
-        return x + y, aux, new_cache
-    if kind == "mla":
-        h, new_cache = MLA.mla_apply(
-            p["attn"], _norm(cfg, p["ln1"], x), cfg.mla,
-            positions=positions, cache=cache, rope_theta=cfg.rope_theta,
-            norm_eps=cfg.norm_eps)
-        x = x + h
-        y = L.mlp_apply(p["mlp"], _norm(cfg, p["ln2"], x), cfg.mlp_kind)
-        return x + y, aux, new_cache
+        return x + y, aux, new_cache, stats
     if kind == "rglru":
         h, new_cache = RG.rglru_block_apply(
             p["rec"], _norm(cfg, p["ln1"], x), cfg.rglru, cache=cache)
         x = x + h
         y = L.mlp_apply(p["mlp"], _norm(cfg, p["ln2"], x), cfg.mlp_kind)
-        return x + y, aux, new_cache
+        return x + y, aux, new_cache, {}
     if kind == "mlstm":
         h, new_cache = XL.mlstm_block_apply(
             p["cell"], _norm(cfg, p["ln1"], x), cfg.xlstm, cache=cache)
-        return x + h, aux, new_cache
+        return x + h, aux, new_cache, {}
     if kind == "slstm":
         h, new_cache = XL.slstm_block_apply(p["cell"], x, cfg.xlstm,
                                             cache=cache)
-        return x + h, aux, new_cache
+        return x + h, aux, new_cache, {}
     raise ValueError(kind)
 
 
 def block_cache_init(cfg: ArchConfig, kind: str, batch: int, max_seq: int,
                      dtype) -> Params | None:
+    if kind in ("attn", "dense", "moe", "mla") and cfg.mla is not None:
+        return MLA.mla_cache_init(batch, max_seq, cfg.mla, dtype)
     if kind in ("attn", "dense", "moe"):
         return L.attention_cache_init(batch, max_seq,
                                       _attn_dims(cfg, kind), dtype)
@@ -150,8 +155,6 @@ def block_cache_init(cfg: ArchConfig, kind: str, batch: int, max_seq: int,
         return L.attention_cache_init(
             batch, min(max_seq, cfg.attn_window or max_seq),
             _attn_dims(cfg, kind), dtype)
-    if kind == "mla":
-        return MLA.mla_cache_init(batch, max_seq, cfg.mla, dtype)
     if kind == "rglru":
         return RG.rglru_cache_init(batch, cfg.rglru, jnp.float32)
     if kind == "mlstm":
@@ -243,36 +246,53 @@ class Model:
     def apply(self, params: Params, batch: dict,
               ) -> tuple[jax.Array, jax.Array]:
         """Full-sequence forward. Returns (logits fp32, aux_loss)."""
+        return self.forward(params, batch)[:2]
+
+    def forward(self, params: Params, batch: dict,
+                ) -> tuple[jax.Array, jax.Array, dict]:
+        """Full-sequence forward. Returns (logits fp32, aux_loss, stats):
+        ``stats`` stacks each block's counts (``block_apply``) over
+        the blocks that have them, in layer order; empty for most
+        models."""
         cfg = self.cfg
         x = self._frontend(params, batch)
         positions = batch.get("positions")
         aux = jnp.zeros((), jnp.float32)
+        stats: list = []              # per stage: name -> (layers, ...)
+
+        def unit(xx, lp, pattern):
+            acc = jnp.zeros((), jnp.float32)
+            st: list = []
+            for bi, kind in enumerate(pattern):
+                xx, a, _, s = block_apply(lp[f"b{bi}"], xx, cfg, kind,
+                                          positions=positions,
+                                          mesh=self.mesh)
+                acc = acc + a
+                if s:
+                    st.append(s)
+            return xx, acc, st
+
         for (pattern, repeat), sp in zip(cfg.stages, params["stages"]):
             if repeat == 1:
-                def unit(xx, _sp=sp, _pattern=pattern):
-                    acc = jnp.zeros((), jnp.float32)
-                    for bi, kind in enumerate(_pattern):
-                        xx, a, _ = block_apply(_sp[f"b{bi}"], xx, cfg, kind,
-                                               positions=positions,
-                                               mesh=self.mesh)
-                        acc = acc + a
-                    return xx, acc
-                x, a = _maybe_remat(unit, self.remat)(x)
+                x, a, st = _maybe_remat(
+                    lambda xx, _sp=sp, _pattern=pattern:
+                    unit(xx, _sp, _pattern), self.remat)(x)
                 aux = aux + a
+                stats += [jax.tree_util.tree_map(lambda v: v[None], s)
+                          for s in st]
             else:
                 def body(carry, layer_params, _pattern=pattern):
-                    def unit(xx, lp):
-                        acc = jnp.zeros((), jnp.float32)
-                        for bi, kind in enumerate(_pattern):
-                            xx, a, _ = block_apply(lp[f"b{bi}"], xx, cfg,
-                                                   kind, positions=positions,
-                                                   mesh=self.mesh)
-                            acc = acc + a
-                        return xx, acc
                     xx, acc0 = carry
-                    xx, a = _maybe_remat(unit, self.remat)(xx, layer_params)
-                    return (xx, acc0 + a), None
-                (x, aux), _ = jax.lax.scan(body, (x, aux), sp)
+                    xx, a, st = _maybe_remat(
+                        lambda xx, lp: unit(xx, lp, _pattern),
+                        self.remat)(xx, layer_params)
+                    return (xx, acc0 + a), (st or None)
+                (x, aux), st = jax.lax.scan(body, (x, aux), sp)
+                if st is not None:
+                    # (repeat, ...) per block of the unit -> layer order
+                    stats.append(jax.tree_util.tree_map(
+                        lambda *v: jnp.stack(v, 1).reshape(-1, *v[0].shape[1:]),
+                        *st))
         x = _norm(cfg, params["final_norm"], x)
         table = (params["embed"] if cfg.tie_embeddings
                  else params["unembed"])["table"]
@@ -281,10 +301,13 @@ class Model:
         if cfg.logits_softcap:
             c = cfg.logits_softcap
             logits = jnp.tanh(logits / c) * c
-        return logits, aux
+        stats = (jax.tree_util.tree_map(
+            lambda *v: jnp.concatenate(v), *stats) if stats else {})
+        return logits, aux, stats
 
     def loss(self, params: Params, batch: dict) -> tuple[jax.Array, dict]:
-        logits, aux = self.apply(params, batch)
+        """(ce + aux, {"ce", "aux"} and the forward's ``stats``)."""
+        logits, aux, stats = self.forward(params, batch)
         labels = batch["labels"]
         logp = jax.nn.log_softmax(logits, axis=-1)
         nll = -jnp.take_along_axis(logp, labels[..., None].astype(jnp.int32),
@@ -292,7 +315,7 @@ class Model:
         mask = (labels >= 0).astype(jnp.float32)
         ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         total = ce + aux
-        return total, {"ce": ce, "aux": aux}
+        return total, {"ce": ce, "aux": aux, **stats}
 
     # -- decode ---------------------------------------------------------------
 
@@ -320,7 +343,7 @@ class Model:
             if repeat == 1:
                 nc = {}
                 for bi, kind in enumerate(pattern):
-                    x, _, c = block_apply(sp[f"b{bi}"], x, cfg, kind,
+                    x, _, c, _ = block_apply(sp[f"b{bi}"], x, cfg, kind,
                                           cache=sc[f"b{bi}"],
                                           mesh=self.mesh)
                     nc[f"b{bi}"] = c
@@ -330,7 +353,7 @@ class Model:
                     layer_params, layer_cache = slice_
                     nc = {}
                     for bi, kind in enumerate(_pattern):
-                        xx, _, c = block_apply(layer_params[f"b{bi}"], xx,
+                        xx, _, c, _ = block_apply(layer_params[f"b{bi}"], xx,
                                                cfg, kind,
                                                cache=layer_cache[f"b{bi}"],
                                                mesh=self.mesh)

@@ -70,7 +70,7 @@ def make_pipeline_loss(model: Model, mesh, *, microbatches: int,
         def body(carry, layer_params):
             xx = carry
             for bi, kind in enumerate(pattern):
-                xx, _, _ = block_apply(layer_params[f"b{bi}"], xx, cfg, kind)
+                xx, _, _, _ = block_apply(layer_params[f"b{bi}"], xx, cfg, kind)
             return xx, None
         x, _ = jax.lax.scan(
             lambda c, lp: _maybe_remat(

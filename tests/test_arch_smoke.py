@@ -93,7 +93,7 @@ def test_decode_step_shapes(arch):
 
 
 def test_cell_applicability_matrix():
-    """31 runnable cells of 40 (DESIGN.md §6)."""
+    """34 runnable cells of 44 (DESIGN.md §6)."""
     runnable = 0
     for arch in all_archs():
         cfg = get_arch(arch)
@@ -102,4 +102,4 @@ def test_cell_applicability_matrix():
             runnable += ok
             if not ok:
                 assert why
-    assert runnable == 31
+    assert runnable == 34

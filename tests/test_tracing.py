@@ -182,3 +182,57 @@ def test_off_site_touches_nothing(off):
         ph.enter(name, 3)
     assert ph._open is None and ph.take() is None
     assert set(sys.modules) == before
+
+
+def _count_then(x):
+    from repro.core import tracing as tr
+    tr.count("rows", [x, 2 * x])
+    tr.count("pairs", x)
+    return x
+
+
+def test_counters_off_by_default(off):
+    """Off, a body's counters keep nothing and nothing comes home."""
+    assert not tracing.recording()
+    tracing.count("rows", 1)
+    assert not tracing._pending_counts
+    rc.plan("cluster", workers=1)
+    f = rc.future(_count_then, 3)
+    assert rc.value(f) == 3
+    assert f._run.spans is None and tracing.counters() == []
+
+
+@pytest.mark.parametrize("backend", ["cluster", "processes"])
+def test_counters_come_home_with_the_result(on, backend):
+    rc.plan(backend, workers=1)
+    f = rc.future(_count_then, 5, label="counted")
+    assert rc.value(f) == 5
+    got = [c for c in tracing.counters() if c.task_id == f.id]
+    assert [(c.name, c.value) for c in got] == [("rows", [5, 10]),
+                                                ("pairs", 5)]
+    assert {c.label for c in got} == {"counted"}
+    assert all(c.pid != os.getpid() for c in got)
+    g = rc.future(_count_then, 6)
+    assert rc.value(g) == 6
+    assert [c.value for c in tracing.counters() if c.task_id == g.id] \
+        == [[6, 12], 6]
+    tracing.reset()
+    assert tracing.counters() == []
+
+
+def test_counters_come_home_with_a_traced_result(off, monkeypatch):
+    """Tracing off: a profiler session in the worker alone records the
+    counters of the bodies that run under it, and they come home with
+    those futures' results."""
+    monkeypatch.setenv("PYTHONPATH", os.pathsep.join(
+        p for p in (TESTS, os.environ.get("PYTHONPATH")) if p))
+    rc.plan("cluster", workers=1)
+    assert rc.value(rc.future(_count_then, 1)) == 1
+    rc.value(rc.future(_start_profiler))
+    mid = [rc.future(_count_then, i) for i in (2, 3)]
+    assert [rc.value(f) for f in mid] == [2, 3]
+    rc.value(rc.future(_stop_profiler))
+    assert rc.value(rc.future(_count_then, 4)) == 4
+    got = tracing.counters()
+    assert {c.task_id for c in got} == {f.id for f in mid}
+    assert sorted(c.value for c in got if c.name == "pairs") == [2, 3]
